@@ -234,7 +234,7 @@ def _grid_pipeline(fused: bool, tiled: bool = True,
     if fused:
         passes.append(MapFusionPass())
     if tiled:
-        defaults = GridConversionPass.default_tiles("pallas", True)
+        defaults = GridConversionPass.default_tiles("pallas")
         passes.append(MapTilingPass(tile_size=tile_size)
                       if tile_size else
                       MapTilingPass(tile_size=defaults.get("minor"),
@@ -245,7 +245,7 @@ def _grid_pipeline(fused: bool, tiled: bool = True,
 
 
 def _chain_pipeline(name: str, pref) -> PassManager:
-    defaults = GridConversionPass.default_tiles("pallas", True)
+    defaults = GridConversionPass.default_tiles("pallas")
     return PassManager([
         SetExpansionPreferencePass(tuple(pref)),
         ExpandLibraryNodesPass(),

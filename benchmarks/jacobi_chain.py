@@ -73,10 +73,10 @@ def _reference(a, stages=STAGES):
 def _perstage_pipeline():
     """The pallas default pipeline with MapFusionPass removed: every
     stage stays its own scope and converts to its own grid kernel."""
-    tiles = GridConversionPass.default_tiles("pallas", True)
+    tiles = GridConversionPass.default_tiles("pallas")
     return PassManager([
         SetExpansionPreferencePass(("pallas", "xla", "generic")),
-        PipelineFusionPass(interpret=True),
+        PipelineFusionPass(),
         ExpandLibraryNodesPass(),
         VectorizationPass(),
         MapTilingPass(tile_size=tiles.get("minor"),

@@ -83,10 +83,10 @@ def _convblock_reference(x, W, bias):
 
 
 def _perstage_pipeline():
-    tiles = GridConversionPass.default_tiles("pallas", True)
+    tiles = GridConversionPass.default_tiles("pallas")
     return PassManager([
         SetExpansionPreferencePass(("pallas", "xla", "generic")),
-        PipelineFusionPass(interpret=True),
+        PipelineFusionPass(),
         ExpandLibraryNodesPass(),
         VectorizationPass(),
         MapTilingPass(tile_size=tiles.get("minor"),

@@ -23,7 +23,7 @@ import sys
 import traceback
 
 
-def main() -> int:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     ap.add_argument("--small", action="store_true",
@@ -33,7 +33,10 @@ def main() -> int:
     ap.add_argument("--calibrate", action="store_true",
                     help="sweep tile sizes per module and record the "
                          "measured crossover")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+
+    from repro.codegen.device import enable_compile_cache
+    enable_compile_cache()
 
     from . import (axpydot, gemver, jacobi_chain, lenet, serve_bench,
                    stencil_bench)

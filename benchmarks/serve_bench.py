@@ -306,8 +306,7 @@ def _faulted_row(report, small: bool, new_tokens: int, max_model_len: int):
 
 
 if __name__ == "__main__":
-    import subprocess
     import sys
-    raise SystemExit(subprocess.call(
-        [sys.executable, "-m", "benchmarks.run", "--only", "serve"]
-        + sys.argv[1:]))
+
+    from benchmarks import run
+    raise SystemExit(run.main(["--only", "serve"] + sys.argv[1:]))

@@ -11,12 +11,14 @@ import time
 import numpy as np
 
 import repro.kernels  # noqa: F401
+from repro.codegen.device import enable_compile_cache
 from repro.frontends.ml import build_lenet, init_lenet_params, lenet_reference
 from repro.pipeline import (DeviceOffloadPass, InputToConstantPass,
                             StreamingCompositionPass, lower)
 
 
 def main():
+    enable_compile_cache()
     batch = 100
     params = init_lenet_params()
     rng = np.random.default_rng(0)

@@ -14,6 +14,7 @@ Run: PYTHONPATH=src python examples/quickstart.py
 import numpy as np
 
 import repro.kernels  # noqa: F401  (register fused kernels)
+from repro.codegen.device import enable_compile_cache
 from repro.frontends import blas
 from repro.frontends.api import dc_program
 from repro.pipeline import (COMPILATION_CACHE, PassManager,
@@ -29,6 +30,7 @@ def axpydot(p, n):
 
 
 def main():
+    enable_compile_cache()
     n = 1 << 20
     rng = np.random.default_rng(0)
     a = np.float32(0.7)

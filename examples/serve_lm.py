@@ -42,6 +42,7 @@ if "--cluster-sim" in sys.argv:
 import jax
 import numpy as np
 
+from repro.codegen.device import enable_compile_cache
 from repro.configs import get_config
 from repro.models import build_model
 from repro.pipeline.cache import COMPILATION_CACHE
@@ -141,6 +142,7 @@ def main():
         args.tokens = min(args.tokens, 8)
         args.max_model_len = min(args.max_model_len, 64)
         args.page_size = min(args.page_size, 8)
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     if args.cluster_sim:

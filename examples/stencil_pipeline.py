@@ -9,6 +9,7 @@ Run: PYTHONPATH=src python examples/stencil_pipeline.py
 import numpy as np
 
 import repro.kernels  # noqa: F401
+from repro.codegen.device import enable_compile_cache
 from repro.frontends.stencil import build_stencil_program
 from repro.kernels.stencil import stencil2d_ref
 from repro.pipeline import (DeviceOffloadPass, StreamingCompositionPass,
@@ -29,6 +30,7 @@ PROGRAM = {
 
 
 def main():
+    enable_compile_cache()
     print("== parse JSON program ->", len(PROGRAM["program"]),
           "stencil operators")
     staged = lower(build_stencil_program(PROGRAM))

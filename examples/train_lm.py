@@ -27,6 +27,7 @@ if "--cluster-sim" in sys.argv:
 
 import dataclasses
 
+from repro.codegen.device import enable_compile_cache
 from repro.configs.base import ModelConfig
 from repro.launch.mesh import make_smoke_mesh
 from repro.runtime import Trainer, TrainerConfig
@@ -97,6 +98,7 @@ def main():
     ap.add_argument("--die-at", type=int, default=6,
                     help="cluster-sim: step at which host 1 dies")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.cluster_sim:
         run_cluster_sim(args)

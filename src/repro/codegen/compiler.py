@@ -28,7 +28,7 @@ CompiledSDFG = Compiled
 
 
 def compile_sdfg(sdfg: SDFG, backend: str = "jnp", jit: bool = True,
-                 interpret: bool = True,
+                 interpret: Optional[bool] = None,
                  expansion_level: Optional[str] = None) -> Compiled:
     return Lowered(sdfg).compile(
         backend=backend, jit=jit, interpret=interpret,

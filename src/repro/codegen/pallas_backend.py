@@ -63,6 +63,7 @@ from ..core.memlet import (BlockFactorError, SubsetFactorization,
 from ..core.sdfg import (MapEntry, MapExit, Scalar, SDFG, State, Stream,
                          Tasklet)
 from ..transforms.map_tiling import normalize_tiling
+from .device import resolve_interpret
 from .common import (WCR_MODES, _apply_wcr, wcr_combine, wcr_identity,
                      wcr_reduce)
 from .jnp_backend import StateLowering, build_callable as _build_callable
@@ -533,6 +534,108 @@ def _conds(ids, positions, sizes, at_end: bool):
     return functools.reduce(jnp.logical_and, conds)
 
 
+#: TPU lane width: a dynamic slice start on a ref's minor dimension must
+#: be a provable multiple of it.
+LANES = 128
+#: largest 32-bit operand placed whole in SMEM (scalar memory is small)
+SMEM_MAX_BYTES = 16 * 1024
+
+
+@dataclass(frozen=True)
+class OperandPlacement:
+    """How one deduplicated input operand reaches the kernel.
+
+    ``smem``: a one-element-per-step 32-bit operand rides whole in SMEM
+    and the body reads its scalar by grid index (a ``(1,)`` VMEM block of
+    a longer vector is refused by the TPU compiler). ``view``: the 2-D
+    shape a 1-D operand is passed as (see :func:`_vector_view`).
+    ``pad``: trailing elements appended per dimension so lane-aligned
+    window loads stay in bounds."""
+    smem: bool = False
+    view: Optional[str] = None
+    pad: Tuple[int, ...] = ()
+
+
+def _vector_view(block: int, n: int, windowed: bool) -> str:
+    """The 2-D view a 1-D operand takes on the TPU, which lays 1-D arrays
+    out in 1024-element tiles that kernel blocks do not match: ``"row"``
+    ``(1, n)`` for lane-sized blocks and windows, ``"col"`` ``(n, 1)`` for
+    blocks that only fill sublanes (the row index of an (8, 128) tile)."""
+    if windowed or block % LANES == 0 or block == n:
+        return "row"
+    return "col"
+
+
+def _view_block(view: Optional[str], block: Tuple[int, ...], index_map):
+    """BlockSpec shape and index map of an operand seen through ``view``."""
+    if view == "row":
+        return (1,) + block, lambda *ids: (0,) + tuple(index_map(*ids))
+    if view == "col":
+        return block + (1,), lambda *ids: tuple(index_map(*ids)) + (0,)
+    return block, index_map
+
+
+def _view_shape(view: Optional[str], shape: Tuple[int, ...]):
+    return {"row": (1,) + shape, "col": shape + (1,)}.get(view, shape)
+
+
+def _lane_window(expr, ln: int):
+    """``(aligned_const, offset, extent)`` when every grid coefficient of
+    a window start is a lane multiple: the body loads ``extent`` lanes at
+    a provably aligned start and slices ``[offset, offset + ln)`` out of
+    them statically. ``None`` when the start cannot be aligned."""
+    c0 = 0
+    for mono, c in expr.terms.items():
+        if mono == ():
+            c0 = int(c)
+        elif int(c) % LANES or int(c) < 0:
+            return None
+    if c0 < 0:
+        return None
+    base = c0 // LANES * LANES
+    off = c0 - base
+    return base, off, -(-(off + ln) // LANES) * LANES
+
+
+def _max_affine(expr, grid_sizes: Dict[str, int]) -> int:
+    """Largest value of a non-negative-coefficient affine expression over
+    the grid."""
+    hi = 0
+    for mono, c in expr.terms.items():
+        hi += int(c) if mono == () else int(c) * (grid_sizes[mono[0][0]] - 1)
+    return hi
+
+
+def _place_operand(es: EdgeSpec, edges: List[EdgeSpec], value,
+                   grid_sizes: Dict[str, int]) -> OperandPlacement:
+    import numpy as np
+    squeezed = es.scalar or (
+        len(es.fact.squeeze_dims) == len(es.fact.block_shape)
+        and not es.fact.param_dims)
+    if (squeezed and not es.fact.windows
+            and int(np.prod(es.fact.block_shape)) == 1
+            and value.dtype.itemsize == 4
+            and value.size * 4 <= SMEM_MAX_BYTES):
+        return OperandPlacement(smem=True)
+    rank = value.ndim
+    pad = [0] * rank
+    for e in edges:
+        for d, expr, ln in e.fact.windows:
+            lw = _lane_window(expr, ln) if d == rank - 1 else None
+            if lw is not None:
+                _, off, ext = lw
+                # the last aligned load ends at (largest start - off) + ext
+                need = _max_affine(expr, grid_sizes) - off + ext
+                pad[d] = max(pad[d], need - value.shape[d])
+    pad = tuple(max(0, p) for p in pad)
+    view = None
+    if rank == 1:
+        view = _vector_view(es.fact.block_shape[0] + pad[0],
+                            value.shape[0] + pad[0],
+                            any(e.fact.windows for e in edges))
+    return OperandPlacement(view=view, pad=pad)
+
+
 class PallasStateLowering(StateLowering):
     """State lowering that emits ``pl.pallas_call`` grid kernels for map
     scopes annotated by ``GridConversionPass`` and shares the structural
@@ -681,41 +784,23 @@ class PallasStateLowering(StateLowering):
     # ------------------------------------------------------------------
     def _emit_grid_kernel(self, entry: MapEntry, chain: List[Tasklet],
                           spec: GridSpec):
-        interpret = self.sdfg.metadata.get("pallas_interpret", True)
+        interpret = resolve_interpret(self.sdfg.metadata.get("pallas_interpret"))
         grid_names = [p for p, _ in spec.grid]
         grid_sizes = tuple(n for _, n in spec.grid)
         block_order = [q for q, _ in spec.block_params]
         bp = dict(spec.block_params)
         tile_shape = tuple(n for _, n in spec.block_params)
 
-        op_reps = unique_operands(spec)
-        op_index = {operand_key(es): i for i, es in enumerate(op_reps)}
-        op_of_edge = [op_index[operand_key(es)] for es in spec.inputs]
+        op_reps, op_of_edge, in_vals, in_specs, places = \
+            self._input_operands(spec)
 
-        in_vals = []
-        for es in op_reps:
-            v = jnp.asarray(self.ensure_value(es.data))
-            if es.scalar:
-                v = jnp.reshape(v, (1,))
-            in_vals.append(v)
-        in_specs = [pl.BlockSpec(es.fact.block_shape,
-                                 es.fact.index_map(grid_names))
-                    for es in op_reps]
-
-        prev_vals, out_specs, out_shapes = [], [], []
+        out_specs, out_shapes, out_views = self._output_operands(spec)
         scratch_shapes, scratch_index = [], {}
         for oi, es in enumerate(spec.outputs):
-            pv = jnp.asarray(self.ensure_value(es.data))
-            if es.scalar:
-                pv = jnp.reshape(pv, (1,))
-            prev_vals.append(pv)
-            out_specs.append(pl.BlockSpec(es.fact.block_shape,
-                                          es.fact.index_map(grid_names)))
-            out_shapes.append(jax.ShapeDtypeStruct(pv.shape, pv.dtype))
             if es.wcr in WCR_MODES and es.reduction:
                 scratch_index[oi] = len(scratch_shapes)
-                scratch_shapes.append(
-                    pltpu.VMEM(es.fact.block_shape, pv.dtype))
+                scratch_shapes.append(pltpu.VMEM(out_specs[oi].block_shape,
+                                                 out_shapes[oi].dtype))
 
         chain_call = self._chain_runner(chain, spec)
         whole_block = self._whole_block_eligible(spec, chain_call, chain)
@@ -727,8 +812,8 @@ class PallasStateLowering(StateLowering):
             scratch = refs[n_ops + n_out:]
             ids = [pl.program_id(k) for k in range(len(grid_names))]
             id_env = dict(zip(grid_names, ids))
-            opvals = self._load_operands(spec, ins, op_of_edge, block_order,
-                                         id_env)
+            opvals = self._load_operands(spec, ins, op_of_edge, places,
+                                         block_order, id_env)
 
             if whole_block:
                 # one array-level application over the whole tile: pad
@@ -772,10 +857,7 @@ class PallasStateLowering(StateLowering):
                             wcr_identity(es.wcr, jnp.asarray(val).dtype))
                 val = self._assemble_block(val, es, block_order)
                 if es.fact.windows:
-                    idx = [slice(None)] * len(es.fact.block_shape)
-                    for d, expr, ln in es.fact.windows:
-                        idx[d] = pl.ds(eval_affine(expr, id_env), ln)
-                    oref[tuple(idx)] = val.astype(oref.dtype)
+                    self._store(oref, val, es, id_env, out_views[oi])
                 elif es.wcr in WCR_MODES and es.reduction:
                     acc = scratch[scratch_index[oi]]
                     red_pos = [grid_names.index(p) for p in es.reduction]
@@ -787,34 +869,128 @@ class PallasStateLowering(StateLowering):
                         acc[...] = jnp.full(
                             acc.shape, wcr_identity(es.wcr, acc.dtype))
 
-                    acc[...] = wcr_combine(es.wcr, acc[...],
-                                           val.astype(acc.dtype))
+                    acc[...] = wcr_combine(
+                        es.wcr, acc[...],
+                        jnp.reshape(val, acc.shape).astype(acc.dtype))
 
                     @pl.when(last)
                     def _flush(acc=acc, oref=oref):
                         oref[...] = acc[...].astype(oref.dtype)
                 else:
-                    oref[...] = val.astype(oref.dtype)
+                    self._store(oref, val, es, id_env, out_views[oi])
 
         results = pl.pallas_call(
             kernel, grid=grid_sizes, in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shapes, scratch_shapes=scratch_shapes,
             interpret=interpret)(*in_vals)
-        if not isinstance(results, (list, tuple)):
-            results = (results,)
         self._stitch_results(spec, results)
 
+    def _output_operands(self, spec: GridSpec):
+        """Output BlockSpecs, shapes and 1-D views (a 1-D output is
+        produced through its :func:`_vector_view`)."""
+        grid_names = [p for p, _ in spec.grid]
+        out_specs, out_shapes, views = [], [], []
+        for es in spec.outputs:
+            pv = jnp.asarray(self.ensure_value(es.data))
+            shape = (1,) if es.scalar else tuple(pv.shape)
+            view = _vector_view(es.fact.block_shape[0], shape[0],
+                                bool(es.fact.windows)) \
+                if len(shape) == 1 else None
+            block, index_map = _view_block(view, tuple(es.fact.block_shape),
+                                           es.fact.index_map(grid_names))
+            out_specs.append(pl.BlockSpec(block, index_map))
+            out_shapes.append(jax.ShapeDtypeStruct(_view_shape(view, shape),
+                                                   pv.dtype))
+            views.append(view)
+        return out_specs, out_shapes, views
+
     @staticmethod
-    def _load_operands(spec: GridSpec, ins, op_of_edge, block_order, id_env):
-        """Per-input-edge kernel values: dedup'd VMEM block, window slice,
-        squeeze, tile axes moved to the front in block-param order."""
-        raw = [ref[...] for ref in ins]
+    def _store(oref, val, es: EdgeSpec, id_env, view: Optional[str]):
+        """Write one output block (or its window) into the output ref."""
+        lead = 1 if view == "row" else 0
+        idx = [slice(None)] * len(oref.shape)
+        for d, expr, ln in es.fact.windows:
+            idx[d + lead] = pl.ds(eval_affine(expr, id_env), ln)
+        val = jnp.reshape(val, _view_shape(view, jnp.shape(val)))
+        oref[tuple(idx)] = val.astype(oref.dtype)
+
+    def _input_operands(self, spec: GridSpec):
+        """Deduplicated input operands with their placements, the values
+        handed to ``pallas_call`` and their BlockSpecs."""
+        grid_names = [p for p, _ in spec.grid]
+        grid_sizes = dict(spec.grid)
+        op_reps = unique_operands(spec)
+        op_index = {operand_key(es): i for i, es in enumerate(op_reps)}
+        op_of_edge = [op_index[operand_key(es)] for es in spec.inputs]
+        in_vals, in_specs, places = [], [], []
+        for oi, es in enumerate(op_reps):
+            v = jnp.asarray(self.ensure_value(es.data))
+            if es.scalar:
+                v = jnp.reshape(v, (1,))
+            edges = [e for e, o in zip(spec.inputs, op_of_edge) if o == oi]
+            place = _place_operand(es, edges, v, grid_sizes)
+            block = list(es.fact.block_shape)
+            if any(place.pad):
+                v = jnp.pad(v, [(0, p) for p in place.pad])
+                block = [b + p for b, p in zip(block, place.pad)]
+            if place.smem:
+                bspec = pl.BlockSpec(memory_space=pltpu.SMEM)
+            else:
+                v = jnp.reshape(v, _view_shape(place.view, v.shape))
+                bspec = pl.BlockSpec(*_view_block(
+                    place.view, tuple(block), es.fact.index_map(grid_names)))
+            in_vals.append(v)
+            in_specs.append(bspec)
+            places.append(place)
+        return op_reps, op_of_edge, in_vals, in_specs, places
+
+    @staticmethod
+    def _load_edge(ref, es: EdgeSpec, place: OperandPlacement, id_env):
+        """One input edge's value out of its operand's ref: a scalar read
+        from SMEM, or the block with each window sliced at load time (lane
+        windows load an aligned span and slice it statically)."""
+        if place.smem:
+            idx = tuple(eval_affine(e, id_env) for e in es.fact.index_exprs)
+            return ref[idx if not es.scalar else (0,)]
+        lead = (slice(None),) if place.view == "row" else ()
+        trail = (slice(None),) if place.view == "col" else ()
+        rank = len(es.fact.block_shape)
+        idx = [slice(None)] * rank
+        post = [slice(None)] * rank
+        for d, expr, ln in es.fact.windows:
+            start = eval_affine(expr, id_env)
+            lw = _lane_window(expr, ln) if d == rank - 1 else None
+            if lw is None:
+                idx[d] = pl.ds(start, ln)
+                continue
+            base, off, ext = lw
+            idx[d] = pl.ds(pl.multiple_of(start - off, LANES), ext)
+            post[d] = slice(off, off + ln)
+        v = ref[lead + tuple(idx) + trail]
+        if es.fact.windows:
+            v = v[lead + tuple(post) + trail]
+        if place.view is not None:
+            v = jnp.reshape(v, v.shape[1:] if lead else v.shape[:-1])
+        # a padded operand's plain (window-free) dims load whole: trim
+        if any(place.pad):
+            v = v[tuple(slice(0, n) if p and not any(
+                w[0] == d for w in es.fact.windows) else slice(None)
+                for d, (n, p) in enumerate(zip(es.fact.block_shape,
+                                               place.pad)))]
+        return v
+
+    def _load_operands(self, spec: GridSpec, ins, op_of_edge, places,
+                       block_order, id_env):
+        """Per-input-edge kernel values: dedup'd block (or SMEM scalar),
+        window slice, squeeze, tile axes moved to the front in
+        block-param order."""
         opvals = {}
         for i, es in enumerate(spec.inputs):
-            v = raw[op_of_edge[i]]
-            for d, expr, ln in es.fact.windows:
-                v = jax.lax.dynamic_slice_in_dim(
-                    v, eval_affine(expr, id_env), ln, axis=d)
+            o = op_of_edge[i]
+            v = self._load_edge(ins[o], es, places[o], id_env)
+            if places[o].smem:
+                opvals[i] = v  # already the scalar the squeeze would give
+                continue
             if es.fact.squeeze_dims:
                 v = jnp.squeeze(v, axis=es.fact.squeeze_dims)
             pd = dict(es.fact.param_dims)
@@ -900,34 +1076,17 @@ class PallasStateLowering(StateLowering):
         runs once over the kept lattice with the finished values (the
         ``@pl.when`` phase flip of the hand-written reduction kernels)."""
         import numpy as np
-        interpret = self.sdfg.metadata.get("pallas_interpret", True)
+        interpret = resolve_interpret(self.sdfg.metadata.get("pallas_interpret"))
         grid_names = [p for p, _ in spec.grid]
         grid_sizes = tuple(n for _, n in spec.grid)
         block_order = [q for q, _ in spec.block_params]
         bp = dict(spec.block_params)
         tile_shape = tuple(n for _, n in spec.block_params)
 
-        op_reps = unique_operands(spec)
-        op_index = {operand_key(es): i for i, es in enumerate(op_reps)}
-        op_of_edge = [op_index[operand_key(es)] for es in spec.inputs]
+        op_reps, op_of_edge, in_vals, in_specs, places = \
+            self._input_operands(spec)
 
-        in_vals, in_specs = [], []
-        for es in op_reps:
-            v = jnp.asarray(self.ensure_value(es.data))
-            if es.scalar:
-                v = jnp.reshape(v, (1,))
-            in_vals.append(v)
-            in_specs.append(pl.BlockSpec(es.fact.block_shape,
-                                         es.fact.index_map(grid_names)))
-
-        out_specs, out_shapes = [], []
-        for es in spec.outputs:
-            pv = jnp.asarray(self.ensure_value(es.data))
-            if es.scalar:
-                pv = jnp.reshape(pv, (1,))
-            out_specs.append(pl.BlockSpec(es.fact.block_shape,
-                                          es.fact.index_map(grid_names)))
-            out_shapes.append(jax.ShapeDtypeStruct(pv.shape, pv.dtype))
+        out_specs, out_shapes, out_views = self._output_operands(spec)
 
         kept_intra = set(spec.internal_wcr[0].kept_intra)
         kept_order = [q for q in block_order if q in kept_intra]
@@ -947,8 +1106,8 @@ class PallasStateLowering(StateLowering):
             accs = refs[n_ops + n_out:]
             ids = [pl.program_id(k) for k in range(len(grid_names))]
             id_env = dict(zip(grid_names, ids))
-            opvals = self._load_operands(spec, ins, op_of_edge, block_order,
-                                         id_env)
+            opvals = self._load_operands(spec, ins, op_of_edge, places,
+                                         block_order, id_env)
 
             if block_order:
                 f1 = chain1_call
@@ -1000,30 +1159,25 @@ class PallasStateLowering(StateLowering):
                                        for q in block_order) + trail)
                         val = jnp.broadcast_to(val, tile_shape + trail)
                     val = self._assemble_block(val, es, block_order)
-                    if es.fact.windows:
-                        idx = [slice(None)] * len(es.fact.block_shape)
-                        for d, expr, ln in es.fact.windows:
-                            idx[d] = pl.ds(eval_affine(expr, id_env), ln)
-                        oref[tuple(idx)] = val.astype(oref.dtype)
-                    else:
-                        oref[...] = val.astype(oref.dtype)
+                    self._store(oref, val, es, id_env, out_views[oi])
 
         results = pl.pallas_call(
             kernel, grid=grid_sizes, in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shapes, scratch_shapes=scratch_shapes,
             interpret=interpret)(*in_vals)
-        if not isinstance(results, (list, tuple)):
-            results = (results,)
         self._stitch_results(spec, results)
 
     def _stitch_results(self, spec: GridSpec, results):
         """Stitch each written box into the prior container contents:
         grid kernels only define the blocks their index maps touch.
         Re-fetch per output: two edges may target the same container."""
+        if not isinstance(results, (list, tuple)):
+            results = (results,)
         for es, new in zip(spec.outputs, results):
             prev = jnp.asarray(self.ensure_value(es.data))
             if es.scalar:
                 prev = jnp.reshape(prev, (1,))
+            new = jnp.reshape(new, prev.shape)  # 1-D outputs come as rows
             sl = tuple(slice(lo, hi) for lo, hi in es.box)
             if es.wcr in WCR_MODES:
                 cur = _apply_wcr(prev.at[sl], es.wcr, new[sl])

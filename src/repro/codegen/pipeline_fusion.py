@@ -12,7 +12,7 @@ materializes), mirroring the paper's fallback to generic expansions.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from ..core.sdfg import AccessNode, LibraryNode, SDFG, State, Stream, Tasklet
 
@@ -78,7 +78,10 @@ def _stream_chains(state: State, sdfg: SDFG) -> List[List[LibraryNode]]:
     return chains
 
 
-def fuse_stream_pipelines(sdfg: SDFG, interpret: bool = True) -> List[str]:
+def fuse_stream_pipelines(sdfg: SDFG,
+                          interpret: Optional[bool] = None) -> List[str]:
+    from .device import resolve_interpret
+    interpret = resolve_interpret(interpret)
     fused = []
     for state in sdfg.states:
         for full_chain in _stream_chains(state, sdfg):

@@ -4,7 +4,7 @@
 shapes and map ranges by ``n_shards`` and stamps the partition under
 ``sdfg.metadata["shard_map"]``; the backend's built callable therefore
 computes ONE shard. This module wraps it in
-``jax.experimental.shard_map.shard_map`` over a 1-D device mesh so the
+``jax.shard_map`` over a 1-D device mesh so the
 global-shaped call runs every shard in parallel: shard-local containers
 get ``PartitionSpec(axis)`` on their partition dim, replicated ones
 ``PartitionSpec()``, and collective outputs (wcr reduced over the
@@ -36,9 +36,8 @@ def make_shard_mesh(n_shards: int, axis: str):
     if len(devs) < n_shards:
         raise ShardMeshError(
             f"shard mesh needs {n_shards} devices but only {len(devs)} "
-            f"are visible; set XLA_FLAGS=--xla_force_host_platform_"
-            f"device_count={n_shards} (before importing jax) or run on "
-            f"a pod slice")
+            f"{jax.default_backend()} devices are visible; run on a host "
+            f"or slice with at least {n_shards} chips")
     return jax.sharding.Mesh(np.array(devs[:n_shards]), (axis,))
 
 
@@ -55,7 +54,6 @@ def wrap_shard_map(fn, spec: Dict, written):
     ``spec`` is the ``sdfg.metadata["shard_map"]`` stamp; ``written`` the
     output container names (the dict keys ``fn`` returns).
     """
-    from jax.experimental.shard_map import shard_map
     import jax
 
     axis = spec["axis"]
@@ -80,8 +78,8 @@ def wrap_shard_map(fn, spec: Dict, written):
                     out[n] = jax.lax.psum(out[n], axis)
             return {n: out[n] for n in sorted(out)}
 
-        return shard_map(inner, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)(
+        return jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(
             [kwargs[n] for n in names])
 
     sharded.__name__ = getattr(fn, "__name__", "sdfg") + f"_shard{k}"

@@ -18,12 +18,15 @@ serving default.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ...codegen.device import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -47,7 +50,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *, scale, window,
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def decode_attention(q, k, v, pos, *, window: int = None,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """q: (B, H, Dh); k/v: (B, C, H, Dh) gathered context; pos: (B,) int32
     absolute position of the current token -> (B, H, Dh).
 
@@ -75,5 +78,5 @@ def decode_attention(q, k, v, pos, *, window: int = None,
                           ctx=c),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pos.astype(jnp.int32), q, k, v)

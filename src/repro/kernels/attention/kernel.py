@@ -14,12 +14,15 @@ blocks that are fully masked are skipped via jnp.where on block indices
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ...codegen.device import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -67,7 +70,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = None,
-                    bq: int = 128, bk: int = 128, interpret: bool = True):
+                    bq: int = 128, bk: int = 128,
+                    interpret: Optional[bool] = None):
     """q: (B, Sq, Hq, Dh); k/v: (B, Sk, Hkv, Dh) -> (B, Sq, Hq, Dh)."""
     b, sq, hq, dh = q.shape
     _, sk, hkv, _ = k.shape
@@ -106,6 +110,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = None,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qh, kh, vh)
     return out.reshape(b, hq, sq, dh).transpose(0, 2, 1, 3)

@@ -13,11 +13,14 @@ the loop-carried add dependency; a final reduction collapses it.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ...codegen.device import resolve_interpret
 
 SUBLANES, LANES = 8, 128
 TILE = SUBLANES * LANES  # 1024-element accumulation tile
@@ -43,7 +46,8 @@ def _axpydot_kernel(a_ref, x_ref, y_ref, w_ref, o_ref, acc_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def axpydot(a, x, y, w, block_n: int = 8 * TILE, interpret: bool = True):
+def axpydot(a, x, y, w, block_n: int = 8 * TILE,
+            interpret: Optional[bool] = None):
     n = x.shape[0]
     block_n = min(block_n, n)
     if block_n % TILE != 0 or n % block_n != 0:
@@ -72,5 +76,5 @@ def axpydot(a, x, y, w, block_n: int = 8 * TILE, interpret: bool = True):
         out_specs=pl.BlockSpec((1,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((1,), jnp.float32),
         scratch_shapes=[pltpu.VMEM((SUBLANES, LANES), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_arr, x, y, w)

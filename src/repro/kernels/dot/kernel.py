@@ -7,11 +7,14 @@ collapses the tile. Used by the Dot Library Node's ``pallas`` expansion.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ...codegen.device import resolve_interpret
 
 SUBLANES, LANES = 8, 128
 TILE = SUBLANES * LANES
@@ -33,7 +36,7 @@ def _dot_kernel(x_ref, w_ref, o_ref, acc_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def dot(x, w, block_n: int = 8 * TILE, interpret: bool = True):
+def dot(x, w, block_n: int = 8 * TILE, interpret: Optional[bool] = None):
     n = x.shape[0]
     block_n = min(block_n, max(n, TILE))
     if block_n % TILE != 0 or n % block_n != 0:
@@ -54,5 +57,5 @@ def dot(x, w, block_n: int = 8 * TILE, interpret: bool = True):
         out_specs=pl.BlockSpec((1,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((1,), jnp.float32),
         scratch_shapes=[pltpu.VMEM((SUBLANES, LANES), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w)

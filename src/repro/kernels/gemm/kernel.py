@@ -13,11 +13,14 @@ activation) plays the role of a downstream streaming-composed PE.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ...codegen.device import resolve_interpret
 
 MXU = 128
 
@@ -79,7 +82,7 @@ def _pad_to(x, m0, m1):
     "bm", "bk", "bn", "activation", "interpret", "out_dtype"))
 def matmul(a, b, bias=None, *, bm: int = 2 * MXU, bk: int = 4 * MXU,
            bn: int = 2 * MXU, activation: str = None,
-           interpret: bool = True, out_dtype=None):
+           interpret: Optional[bool] = None, out_dtype=None):
     """C = act(A @ B + bias), A:(M,K) B:(K,N), fp32 accumulation."""
     M, K = a.shape
     K2, N = b.shape
@@ -108,7 +111,7 @@ def matmul(a, b, bias=None, *, bm: int = 2 * MXU, bk: int = 4 * MXU,
             out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
             scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(a_p, b_p, bias_p)
     else:
         out = pl.pallas_call(
@@ -122,7 +125,7 @@ def matmul(a, b, bias=None, *, bm: int = 2 * MXU, bk: int = 4 * MXU,
             out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
             scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(a_p, b_p)
     if (Mp, Np) != (M, N):
         out = out[:M, :N]

@@ -12,11 +12,14 @@ Chunk length 16 bounds exp(cumsum log w) within fp32 (|log w| <= 3.5).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ...codegen.device import resolve_interpret
 
 CHUNK = 16
 
@@ -66,7 +69,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_final_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def wkv_chunked(r, k, v, w, u, interpret: bool = True):
+def wkv_chunked(r, k, v, w, u, interpret: Optional[bool] = None):
     """r,k,v,w: (B,S,H,hd); u: (H,hd) -> (out (B,S,H,hd), state (B,H,hd,hd)).
     Zero initial state (prefill); S must be a multiple of CHUNK."""
     B, S, H, hd = r.shape
@@ -98,7 +101,7 @@ def wkv_chunked(r, k, v, w, u, interpret: bool = True):
             jax.ShapeDtypeStruct((B * H, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rf, kf, vf, wf, uf)
     out = out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
     state = state.reshape(B, H, hd, hd)

@@ -15,11 +15,14 @@ PEs feed the shift register.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ...codegen.device import resolve_interpret
 
 
 def _pick_tile(n: int, target: int) -> int:
@@ -30,14 +33,10 @@ def _pick_tile(n: int, target: int) -> int:
 
 
 def _element_block_spec(shape, index_map) -> pl.BlockSpec:
-    """Element-indexed BlockSpec across jax versions: newer jax spells it
-    ``pl.Element`` per dimension; older releases use the ``Unblocked``
-    indexing mode. Both make ``index_map`` return element offsets, which
-    the overlapping halo'd slabs need (slab height is not a multiple of
-    the tile stride)."""
-    if hasattr(pl, "Element"):
-        return pl.BlockSpec(tuple(pl.Element(s) for s in shape), index_map)
-    return pl.BlockSpec(shape, index_map, indexing_mode=pl.Unblocked())
+    """Element-indexed BlockSpec: ``index_map`` returns element offsets,
+    which the overlapping halo'd slabs need (slab height is not a
+    multiple of the tile stride)."""
+    return pl.BlockSpec(tuple(pl.Element(s) for s in shape), index_map)
 
 
 # ---------------------------------------------------------------------------
@@ -56,7 +55,8 @@ def _stencil2d_kernel(c_ref, a_ref, o_ref, *, offsets, radius):
 
 
 @functools.partial(jax.jit, static_argnames=("offsets", "bh", "interpret"))
-def stencil2d(a, coeffs, offsets, bh: int = 256, interpret: bool = True):
+def stencil2d(a, coeffs, offsets, bh: int = 256,
+              interpret: Optional[bool] = None):
     """out[p] = sum_k c_k * a[p + offsets_k], constant-0 boundary."""
     H, W = a.shape
     bh = _pick_tile(H, bh)
@@ -73,7 +73,7 @@ def stencil2d(a, coeffs, offsets, bh: int = 256, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((bh, W), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((H, W), a.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(coeffs, p)
 
 
@@ -118,7 +118,7 @@ def _stencil2d_chain_kernel(c_ref, a_ref, o_ref, *, stages, radii, H, W, bh):
 @functools.partial(jax.jit, static_argnames=("offsets_per_stage", "bh",
                                              "interpret"))
 def stencil2d_chain(a, coeffs_per_stage, offsets_per_stage, bh: int = 256,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """Apply consecutive stencil stages in one fused kernel.
 
     offsets_per_stage: tuple of tuples of (di, dj); coeffs_per_stage: list of
@@ -144,7 +144,7 @@ def stencil2d_chain(a, coeffs_per_stage, offsets_per_stage, bh: int = 256,
         ],
         out_specs=pl.BlockSpec((bh, W), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((H, W), a.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(coeffs, p)
 
 
@@ -161,7 +161,7 @@ def _diffusion2d_kernel(c_ref, a_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bh", "interpret"))
-def diffusion2d(a, coeffs, bh: int = 256, interpret: bool = True):
+def diffusion2d(a, coeffs, bh: int = 256, interpret: Optional[bool] = None):
     H, W = a.shape
     bh = _pick_tile(H, bh)
     p = jnp.pad(a, 1)  # constant-0 boundary
@@ -176,7 +176,7 @@ def diffusion2d(a, coeffs, bh: int = 256, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((bh, W), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((H, W), a.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(coeffs, p)
 
 
@@ -194,7 +194,7 @@ def _jacobi3d_kernel(a_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bd", "interpret"))
-def jacobi3d(a, bd: int = 16, interpret: bool = True):
+def jacobi3d(a, bd: int = 16, interpret: Optional[bool] = None):
     D, H, W = a.shape
     bd = _pick_tile(D, bd)
     p = jnp.pad(a, 1)
@@ -206,7 +206,7 @@ def jacobi3d(a, bd: int = 16, interpret: bool = True):
             lambda i: (i * bd, 0, 0))],
         out_specs=pl.BlockSpec((bd, H, W), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((D, H, W), a.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(p)
 
 
@@ -225,7 +225,8 @@ def _diffusion3d_kernel(alpha_ref, a_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bd", "interpret"))
-def diffusion3d(a, alpha: float = 0.1, bd: int = 16, interpret: bool = True):
+def diffusion3d(a, alpha: float = 0.1, bd: int = 16,
+                interpret: Optional[bool] = None):
     D, H, W = a.shape
     bd = _pick_tile(D, bd)
     p = jnp.pad(a, 1)
@@ -241,5 +242,5 @@ def diffusion3d(a, alpha: float = 0.1, bd: int = 16, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((bd, H, W), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((D, H, W), a.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(alpha_arr, p)

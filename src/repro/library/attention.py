@@ -66,7 +66,8 @@ def _expand_xla(node: "PagedAttnDecode", sdfg: SDFG, state: State):
 
 def _expand_flash(node: "PagedAttnDecode", sdfg: SDFG, state: State):
     window = node.window
-    interpret = bool(sdfg.metadata.get("pallas_interpret", True))
+    from ..codegen.device import resolve_interpret
+    interpret = resolve_interpret(sdfg.metadata.get("pallas_interpret"))
 
     def attn(q, k, v, pos):
         from ..kernels.attention import decode_attention
@@ -97,16 +98,19 @@ def _expand_grid(node: "PagedAttnDecode", sdfg: SDFG, state: State):
     window = node.window
 
     def attn_row(q, k, v, pos):
+        # one query row: multiply-and-reduce on the vector unit (a
+        # matrix-vector product vmapped over heads is a batched dot the
+        # TPU kernel compiler cannot lower)
         qf = q.astype(jnp.float32)
         kf = k.astype(jnp.float32)
-        s = kf @ qf * scale                        # (C,)
+        s = jnp.sum(kf * qf[None, :], axis=-1) * scale   # (C,)
         j = jnp.arange(ctx)
         mask = j <= pos
         if window is not None:
             mask &= j > pos - window
         s = jnp.where(mask, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
-        out = p @ v.astype(jnp.float32)
+        out = jnp.sum(p[:, None] * v.astype(jnp.float32), axis=0)
         return {"out": out.astype(q.dtype)}
 
     b, h = sym("b"), sym("h")

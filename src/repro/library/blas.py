@@ -154,7 +154,8 @@ def _dot_partial_sums(node: Dot, sdfg: SDFG, state: State):
 
 def _dot_pallas(node: Dot, sdfg: SDFG, state: State):
     from ..kernels.dot import ops as dot_ops
-    interpret = sdfg.metadata.get("pallas_interpret", True)
+    from ..codegen.device import resolve_interpret
+    interpret = resolve_interpret(sdfg.metadata.get("pallas_interpret"))
     replace_with_tasklet(
         node, sdfg, state,
         lambda x, w: dot_ops.dot(x, w, interpret=interpret), "pallas")
@@ -356,7 +357,8 @@ def _gemm_xla(node: Gemm, sdfg: SDFG, state: State):
 
 def _gemm_pallas(node: Gemm, sdfg: SDFG, state: State):
     from ..kernels.gemm import ops as gemm_ops
-    interpret = sdfg.metadata.get("pallas_interpret", True)
+    from ..codegen.device import resolve_interpret
+    interpret = resolve_interpret(sdfg.metadata.get("pallas_interpret"))
     replace_with_tasklet(
         node, sdfg, state,
         lambda A, B: gemm_ops.matmul(A, B, interpret=interpret), "pallas")

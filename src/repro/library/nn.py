@@ -11,6 +11,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..codegen.device import resolve_interpret
 from ..codegen.pipeline_fusion import register_fusion
 from ..core.sdfg import LibraryNode, SDFG, State
 from .util import replace_with_tasklet
@@ -60,7 +61,7 @@ def _conv_xla(node: Conv2d, sdfg: SDFG, state: State):
 def _conv_pallas(node: Conv2d, sdfg: SDFG, state: State):
     """im2col + systolic GEMM with fused bias(+activation) epilogue."""
     act = node.activation
-    interpret = sdfg.metadata.get("pallas_interpret", True)
+    interpret = resolve_interpret(sdfg.metadata.get("pallas_interpret"))
 
     def fn(x, W, b):
         from ..kernels.gemm import matmul
@@ -149,7 +150,7 @@ def _linear_xla(node: Linear, sdfg: SDFG, state: State):
 
 def _linear_pallas(node: Linear, sdfg: SDFG, state: State):
     act = node.activation
-    interpret = sdfg.metadata.get("pallas_interpret", True)
+    interpret = resolve_interpret(sdfg.metadata.get("pallas_interpret"))
 
     def fn(x, W, b):
         from ..kernels.gemm import matmul

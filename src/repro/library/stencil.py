@@ -18,6 +18,7 @@ from typing import Sequence, Tuple
 
 import jax.numpy as jnp
 
+from ..codegen.device import resolve_interpret
 from ..codegen.pipeline_fusion import FUSION_REGISTRY
 from ..core.sdfg import LibraryNode, SDFG, State
 from .util import replace_with_tasklet
@@ -50,7 +51,7 @@ def _stencil_xla(node: Stencil, sdfg: SDFG, state: State):
 
 def _stencil_pallas(node: Stencil, sdfg: SDFG, state: State):
     offsets = node.offsets
-    interpret = sdfg.metadata.get("pallas_interpret", True)
+    interpret = resolve_interpret(sdfg.metadata.get("pallas_interpret"))
 
     def fn(a, c):
         from ..kernels.stencil import stencil2d

@@ -23,15 +23,18 @@ def psc(x, *roles):
     roles per dim: 'batch' (shard over pod+data axes), 'model', 'seq_model'
     (sequence over model — long-context decode), or None. Filters to axes
     present in the ambient mesh and checks divisibility, so model code is
-    mesh-agnostic; a no-op without a mesh context (CPU smoke tests).
+    mesh-agnostic. Only ``Auto`` axes are constrained, so it is a no-op
+    without a mesh context (CPU smoke tests) and inside ``shard_map``,
+    where every axis is ``Manual``.
     """
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
+    am = jax.sharding.get_abstract_mesh()
+    if am is None or am.empty:
         return x
-    if am is None or getattr(am, "empty", True):
+    auto = jax.sharding.AxisType.Auto
+    sizes = {a: n for (a, n), t in zip(am.shape.items(), am.axis_types)
+             if t == auto}
+    if not sizes:
         return x
-    sizes = dict(am.shape)
     spec = []
     for dim, role in zip(x.shape, roles):
         if role == "batch":
@@ -142,13 +145,10 @@ def attention_xla(q, k, v, *, causal: bool = True, window: Optional[int] = None,
 
 
 def _model_size() -> int:
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and not getattr(am, "empty", True):
-            return dict(am.shape).get("model", 1)
-    except Exception:
-        pass
-    return 1
+    am = jax.sharding.get_abstract_mesh()
+    if am is None or am.empty:
+        return 1
+    return dict(am.shape).get("model", 1)
 
 
 def attention_chunked(q, k, v, *, causal: bool = True,
@@ -219,7 +219,7 @@ def attention_chunked(q, k, v, *, causal: bool = True,
 
 
 def attention(q, k, v, *, causal=True, window=None, q_offset=0,
-              impl: str = "xla", interpret: bool = True):
+              impl: str = "xla", interpret: Optional[bool] = None):
     if impl == "xla" or q.shape[1] == 1:
         return attention_xla(q, k, v, causal=causal, window=window,
                              q_offset=q_offset)

@@ -209,8 +209,9 @@ class PipelineFusionPass(Pass):
 
     name = "PipelineFusion"
 
-    def __init__(self, interpret: bool = True):
-        self.interpret = interpret
+    def __init__(self, interpret: Optional[bool] = None):
+        from ..codegen.device import resolve_interpret
+        self.interpret = resolve_interpret(interpret)
 
     def apply(self, sdfg: SDFG, report: dict) -> List[str]:
         from ..codegen.pipeline_fusion import fuse_stream_pipelines
@@ -235,8 +236,10 @@ class GridConversionPass(Pass):
     Conversion is gated by a VMEM-aware cost model: a scope only becomes
     a grid kernel when its per-step blocks (double-buffered, plus
     reduction scratch) fit ``vmem_budget_bytes``, its grid has at least
-    ``min_grid_steps`` steps (a one-step grid is a whole-array copy the
-    vmap path does without launch overhead), and its fused chain stays
+    ``min_grid_steps`` steps (by default 2 in the interpreter, where a
+    one-step grid is a whole-array copy the vmap path does without
+    per-step dispatch, and 1 on the chip, where it is one kernel launch
+    like any other), and its fused chain stays
     under ``max_fused_tasklets``. Scopes the model rejects are recorded
     as ``grid_skipped(reason)`` and stay on the vmap path; converted
     scopes are recorded in ``grid_converted`` with their cost estimates.
@@ -259,16 +262,21 @@ class GridConversionPass(Pass):
     CALIBRATED_TILES = {("pallas", True): {"minor": 64, "second": 32}}
 
     @classmethod
-    def default_tiles(cls, backend: str, interpret: bool = True) -> Dict:
+    def default_tiles(cls, backend: str,
+                      interpret: Optional[bool] = None) -> Dict:
         """Per-backend preferred (minor, second) tile widths: the
         calibrated table when a measured entry exists, else empty — the
         caller falls back to the static alignment defaults."""
-        return dict(cls.CALIBRATED_TILES.get((backend, bool(interpret)), {}))
+        from ..codegen.device import resolve_interpret
+        return dict(cls.CALIBRATED_TILES.get(
+            (backend, resolve_interpret(interpret)), {}))
 
     def __init__(self, vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET,
-                 min_grid_steps: int = 2, max_fused_tasklets: int = 16):
+                 min_grid_steps: Optional[int] = None,
+                 max_fused_tasklets: int = 16):
         self.vmem_budget_bytes = int(vmem_budget_bytes)
-        self.min_grid_steps = int(min_grid_steps)
+        self.min_grid_steps = None if min_grid_steps is None \
+            else int(min_grid_steps)
         self.max_fused_tasklets = int(max_fused_tasklets)
 
     def options(self) -> Dict[str, Any]:
@@ -334,13 +342,17 @@ class GridConversionPass(Pass):
                 "in_kernel_values": in_kernel,
                 "tasklets": max(1, len(spec.tasklet_labels))}
 
-    def skip_reason(self, est: Dict[str, int]) -> Optional[str]:
+    def skip_reason(self, est: Dict[str, int],
+                    interpret: bool) -> Optional[str]:
+        min_steps = self.min_grid_steps
+        if min_steps is None:
+            min_steps = 2 if interpret else 1
         if est["vmem_bytes"] > self.vmem_budget_bytes:
             return (f"blocks pin {est['vmem_bytes']} B of VMEM > budget "
                     f"{self.vmem_budget_bytes} B")
-        if est["grid_steps"] < self.min_grid_steps:
+        if est["grid_steps"] < min_steps:
             return (f"grid of {est['grid_steps']} step(s) below "
-                    f"min_grid_steps={self.min_grid_steps}; vmap path wins")
+                    f"min_grid_steps={min_steps}; vmap path wins")
         if est["tasklets"] > self.max_fused_tasklets:
             return (f"{est['tasklets']} fused tasklets exceed "
                     f"max_fused_tasklets={self.max_fused_tasklets}")
@@ -348,10 +360,13 @@ class GridConversionPass(Pass):
 
     def apply(self, sdfg: SDFG, report: dict) -> List[str]:
         from ..analysis.diagnostics import refusal_code, refusal_diagnostic
+        from ..codegen.device import resolve_interpret
         from ..codegen.pallas_backend import (GRID_ANNOTATION,
                                               analyze_map_scope)
         from ..core.memlet import BlockFactorError
         from ..core.sdfg import MapEntry
+
+        interpret = resolve_interpret(sdfg.metadata.get("pallas_interpret"))
 
         # symbols mutated by interstate assignments are not compile-time
         # constants; subsets referencing them must fall back.
@@ -381,7 +396,7 @@ class GridConversionPass(Pass):
                                            str(exc)).to_dict())
                     continue
                 est = self.estimate(spec, sdfg)
-                reason = self.skip_reason(est)
+                reason = self.skip_reason(est, interpret)
                 if reason is not None:
                     node.map.annotations.pop(GRID_ANNOTATION, None)
                     skipped.append((node.map.label, reason))
@@ -631,7 +646,7 @@ def _summarize(result) -> Any:
     return result
 
 
-def default_pipeline(backend: str, interpret: bool = True,
+def default_pipeline(backend: str, interpret: Optional[bool] = None,
                      expansion_level: Optional[str] = None,
                      n_shards: int = 1,
                      shard_axis: str = "shard",

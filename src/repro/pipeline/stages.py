@@ -143,7 +143,7 @@ class Lowered(Stage):
         return self
 
     def compile(self, backend: str = "jnp", jit: bool = True,
-                interpret: bool = True,
+                interpret: Optional[bool] = None,
                 expansion_level: Optional[str] = None,
                 pipeline: Optional[PassManager] = None,
                 cache: Optional[CompilationCache] = COMPILATION_CACHE,
@@ -166,7 +166,9 @@ class Lowered(Stage):
         served where a verification record was requested.
         """
         from ..codegen import get_backend
+        from ..codegen.device import resolve_interpret
         backend_mod = get_backend(backend)  # validates the name early
+        interpret = resolve_interpret(interpret)
         pm = pipeline if pipeline is not None else default_pipeline(
             backend, interpret=interpret, expansion_level=expansion_level)
         if verify is None:
@@ -176,7 +178,7 @@ class Lowered(Stage):
         key = None
         if cache is not None:  # content_hash walks the whole graph
             key = (self._sdfg.content_hash(), backend, pm.signature(),
-                   bool(jit)) + ((verify,) if verify else ())
+                   bool(jit), interpret) + ((verify,) if verify else ())
             hit = cache.lookup(key)
             if hit is not None:
                 return hit
@@ -186,11 +188,12 @@ class Lowered(Stage):
         if backend == "pallas":
             # honored by pipeline-fused and generated grid kernels alike;
             # an explicit PipelineFusionPass(interpret=...) overrides.
-            work.metadata["pallas_interpret"] = bool(interpret)
+            work.metadata["pallas_interpret"] = interpret
         report = {"backend": backend, "fused_regions": [], "expansions": [],
                   "passes": [], "grid_kernels": [], "grid_converted": [],
                   "grid_skipped": [], "grid_fallbacks": [],
-                  "pipeline": pm.name}
+                  "pipeline": pm.name,
+                  "interpret": interpret if backend == "pallas" else None}
         pm.run(work, report=report, verify=verify)
         work.validate()
 

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..codegen.device import resolve_interpret
 from ..core.memlet import Memlet
 from ..frontends.api import Program, TensorHandle, dc_program
 from ..library import PagedAttnDecode
@@ -66,32 +67,35 @@ def attention_layer_shapes(model) -> Dict[int, Tuple[int, int]]:
             if spec.kind == "attn"}
 
 
-def flatten_params(model, params) -> Dict[str, jnp.ndarray]:
+def flatten_params(model, params, place=None) -> Dict[str, jnp.ndarray]:
     """Stacked tree -> flat ``L{li}__{group}__{key}`` arrays (+ head/embed).
 
     Iteration order is deterministic (periods outer, positions inner,
     matching the scan's execution order), so two flattenings of the same
     model produce identical container orders and the built SDFGs
-    content-hash equal.
+    content-hash equal. ``place`` (e.g. a ``device_put`` onto a mesh) is
+    applied to each array as it is sliced out, so at most one unplaced
+    layer slice is alive at a time.
     """
-    out: Dict[str, jnp.ndarray] = {"embed": params["embed"]}
+    place = place or (lambda a: a)
+    out: Dict[str, jnp.ndarray] = {"embed": place(params["embed"])}
     li = 0
     for pp in range(model.n_periods):
         for pi in range(len(model.period_specs)):
             for gname, gdict in params["body"][pi].items():
                 for k, a in gdict.items():
-                    out[f"L{li}__{gname}__{k}"] = a[pp]
+                    out[f"L{li}__{gname}__{k}"] = place(a[pp])
             li += 1
     for ti in range(len(model.tail_specs)):
         for gname, gdict in params["tail"][ti].items():
             for k, a in gdict.items():
-                out[f"L{li}__{gname}__{k}"] = a
+                out[f"L{li}__{gname}__{k}"] = place(a)
         li += 1
-    out["final_scale"] = params["final_scale"]
+    out["final_scale"] = place(params["final_scale"])
     if "final_bias" in params:
-        out["final_bias"] = params["final_bias"]
+        out["final_bias"] = place(params["final_bias"])
     if not model.cfg.tie_embeddings:
-        out["lm_head"] = params["lm_head"]
+        out["lm_head"] = place(params["lm_head"])
     return out
 
 
@@ -382,7 +386,7 @@ def _recurrent_layer(p, cfg, li, kind, apply_fn, x, w, sth, sspecs, B, D):
 # ---------------------------------------------------------------------------
 # Pipelines + bucketed compile wrapper
 # ---------------------------------------------------------------------------
-def decode_pipeline(interpret: bool = True,
+def decode_pipeline(interpret: Optional[bool] = None,
                     dtype_aware_sublanes: bool = False,
                     n_shards: int = 1, shard_axis: str = "shard",
                     mesh_sig: Optional[str] = None) -> PassManager:
@@ -475,7 +479,7 @@ class DecodeStepCompiler:
     """
 
     def __init__(self, model, params, *, page_size: int, n_pages: int,
-                 cache_dtype="bfloat16", interpret: bool = True,
+                 cache_dtype="bfloat16", interpret: Optional[bool] = None,
                  dtype_aware_sublanes: bool = False,
                  cache: Optional[CompilationCache] = None,
                  donate: bool = True, max_compile_backoff: int = 32,
@@ -485,7 +489,7 @@ class DecodeStepCompiler:
         self.page_size = page_size
         self.n_pages = n_pages
         self.cache_dtype = str(jnp.dtype(cache_dtype))
-        self.interpret = interpret
+        self.interpret = resolve_interpret(interpret)
         self.dtype_aware_sublanes = dtype_aware_sublanes
         self.cache = COMPILATION_CACHE if cache is None else cache
         self.donate = donate
@@ -498,7 +502,8 @@ class DecodeStepCompiler:
         self.mesh_sig = mesh_sig
         self.compile_fault = None  # optional fn(B, ctx) raising to inject
         self.events: List[dict] = []
-        self.flat_weights = flatten_params(model, params)
+        self.flat_weights = flatten_params(model, params,
+                                           place=self._replicate())
         self._wspecs = {n: (tuple(int(s) for s in a.shape), str(a.dtype))
                         for n, a in self.flat_weights.items()}
         self._steps: Dict[Tuple[int, int], CompiledDecodeStep] = {}
@@ -509,6 +514,24 @@ class DecodeStepCompiler:
             {f"kp{li}" for li in attention_layer_shapes(model)} |
             {f"vp{li}" for li in attention_layer_shapes(model)} |
             set(state_specs(model)))
+
+    def _replicate(self):
+        """Weight placement for a sharded step: one replica per mesh
+        device, put there once. Left to the step call, every step would
+        copy the weights from the default device again."""
+        if self.n_shards <= 1:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec
+        from ..codegen.shard import make_shard_mesh
+        replicated = NamedSharding(make_shard_mesh(self.n_shards,
+                                                   self.shard_axis),
+                                   PartitionSpec())
+        return lambda a: jax.device_put(a, replicated)
+
+    @property
+    def steps(self) -> Dict[Tuple[int, int], CompiledDecodeStep]:
+        """The step serving each (B, ctx) bucket compiled so far."""
+        return dict(self._steps)
 
     def _lowered(self, B: int, ctx: int):
         low = serving_decode_step.lower(

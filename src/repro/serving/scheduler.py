@@ -111,7 +111,7 @@ class Scheduler:
                  max_model_len: int = 256, prefill_chunk: int = 8,
                  cache_dtype="bfloat16",
                  compiler: Optional[DecodeStepCompiler] = None,
-                 interpret: bool = True,
+                 interpret: Optional[bool] = None,
                  dtype_aware_sublanes: bool = False, compile_cache=None,
                  temperature: float = 0.0, top_k: Optional[int] = None,
                  seed: int = 0,

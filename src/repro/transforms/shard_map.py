@@ -37,7 +37,7 @@ are statically opaque; two escape hatches cover them:
 ``partition_sdfg`` mutates the SDFG in place — container shapes and map
 ranges divide by ``n_shards`` — and stamps ``sdfg.metadata["shard_map"]``
 (pure data, content-hash safe) for the backend, which wraps the built
-callable in ``jax.experimental.shard_map`` (codegen/shard.py).
+callable in ``jax.shard_map`` (codegen/shard.py).
 """
 from __future__ import annotations
 
