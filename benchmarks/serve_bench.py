@@ -89,12 +89,12 @@ def _compiled_run(model, params, prompts, new_tokens: int,
         sched.submit(list(map(int, prompts[b])), new_tokens)
     reqs = sched.run()
     sched.check_invariants()
-    # steady state: drop the prefill token and the compile-warmup steps
-    steady: List[float] = []
-    for r in reqs:
-        steady.extend(r.token_times[3:])
+    # steady state: the gaps between a request's tokens, without the
+    # first two (the compile-warmup steps)
+    gaps = [np.diff(r.token_times) for r in reqs]
+    steady: List[float] = [t for g in gaps for t in g[2:]]
     if not steady:
-        steady = [t for r in reqs for t in r.token_times[1:]]
+        steady = [t for g in gaps for t in g]
     med = float(np.median(steady))
     report = sched.compiler._steps[max(sched.compiler._steps)].report
     return (B / med, float(np.percentile(steady, 50) * 1e3),

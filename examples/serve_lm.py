@@ -196,7 +196,8 @@ def main():
     print(f"{'rid':>4} {'prompt':>7} {'new':>4} {'reason':>10} "
           f"{'ttft_ms':>8} {'p50_ms':>7} {'p99_ms':>7}")
     for r in reqs:
-        steady = r.token_times[1:] or r.token_times
+        steady = np.diff(r.token_times) if len(r.token_times) > 1 \
+            else [r.ttft]
         print(f"{r.rid:>4} {len(r.prompt):>7} {len(r.tokens_out):>4} "
               f"{r.finish_reason:>10} {r.ttft * 1e3:>8.1f} "
               f"{np.percentile(steady, 50) * 1e3:>7.2f} "

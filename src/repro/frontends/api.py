@@ -27,6 +27,7 @@ from __future__ import annotations
 import itertools
 from typing import Optional, Sequence, Tuple, Union
 
+from .. import tracing
 from ..core.dtypes import StorageType
 from ..core.memlet import Memlet
 from ..core.sdfg import AccessNode, LibraryNode, SDFG, State
@@ -145,7 +146,8 @@ class Program:
 
     # -- finalize ---------------------------------------------------------
     def finalize(self) -> SDFG:
-        self.sdfg.validate()
+        with tracing.span("frontend", program=self.sdfg.name):
+            self.sdfg.validate()
         return self.sdfg
 
 

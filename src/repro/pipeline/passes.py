@@ -22,9 +22,9 @@ and can then be named in pipelines by string.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
+from .. import tracing
 from ..core.sdfg import SDFG, _stable_repr
 from ..transforms import (DeviceOffload, InputToConstant, MapFusion,
                           MapTiling, StreamingComposition, StreamingMemory,
@@ -602,9 +602,9 @@ class PassManager:
             if p.name in skip_names or p.should_skip(sdfg):
                 entry["skipped"] = True
                 continue
-            t0 = time.perf_counter()
-            entry["summary"] = _summarize(p.apply(sdfg, report))
-            entry["seconds"] = time.perf_counter() - t0
+            with tracing.timed("pass", **{"pass": p.name}) as t:
+                entry["summary"] = _summarize(p.apply(sdfg, report))
+            entry["seconds"] = t.seconds
             if verify:
                 from ..analysis.diagnostics import VerificationError
                 diags = verify_sdfg(sdfg)

@@ -26,6 +26,7 @@ from typing import Any, Iterable, Optional
 
 import jax
 
+from .. import tracing
 from ..core.sdfg import SDFG
 from .cache import COMPILATION_CACHE, CompilationCache
 from .passes import PassManager, PassLike, default_pipeline
@@ -97,8 +98,9 @@ class Wrapped(Stage):
         return sdfg
 
     def lower(self, *args, **kwargs) -> "Lowered":
-        sdfg = self(*args, **kwargs)
-        sdfg.validate()
+        with tracing.span("lower", program=self.__name__):
+            sdfg = self(*args, **kwargs)
+            sdfg.validate()
         return Lowered(sdfg)
 
     def __repr__(self):
@@ -138,7 +140,8 @@ class Lowered(Stage):
         pm = pipeline if isinstance(pipeline, PassManager) \
             else PassManager(pipeline)
         report = {"pipeline": pm.name}
-        pm.run(self._sdfg, report=report, skip=skip)
+        with tracing.span("optimize", program=self._sdfg.name):
+            pm.run(self._sdfg, report=report, skip=skip)
         self.reports.append(report)
         return self
 
@@ -165,6 +168,13 @@ class Lowered(Stage):
         cache separately so a cached non-verified artifact is never
         served where a verification record was requested.
         """
+        with tracing.span("compile", program=self._sdfg.name,
+                          backend=backend):
+            return self._compile(backend, jit, interpret, expansion_level,
+                                 pipeline, cache, in_place, verify)
+
+    def _compile(self, backend, jit, interpret, expansion_level, pipeline,
+                 cache, in_place, verify) -> "Compiled":
         from ..codegen import get_backend
         from ..codegen.device import resolve_interpret
         backend_mod = get_backend(backend)  # validates the name early
@@ -180,6 +190,8 @@ class Lowered(Stage):
             key = (self._sdfg.content_hash(), backend, pm.signature(),
                    bool(jit), interpret) + ((verify,) if verify else ())
             hit = cache.lookup(key)
+            tracing.count("compile_cache.miss" if hit is None
+                          else "compile_cache.hit")
             if hit is not None:
                 return hit
 
@@ -197,7 +209,8 @@ class Lowered(Stage):
         pm.run(work, report=report, verify=verify)
         work.validate()
 
-        fn = backend_mod.build_callable(work)
+        with tracing.span("codegen", program=work.name, backend=backend):
+            fn = backend_mod.build_callable(work)
         jitted = jax.jit(fn) if jit else None
         compiled = Compiled(work, fn, jitted, backend, report, cache_key=key)
         if cache is not None:
@@ -225,8 +238,9 @@ class Compiled(Stage):
         self.cache_key = cache_key
 
     def __call__(self, **kwargs):
-        return self.jitted(**kwargs) if self.jitted is not None \
-            else self.fn(**kwargs)
+        with tracing.span("call", program=self.sdfg.name):
+            return self.jitted(**kwargs) if self.jitted is not None \
+                else self.fn(**kwargs)
 
     def lower(self, **kwargs):
         """Lower the compiled callable through jax (HLO inspection)."""
@@ -241,6 +255,7 @@ class Compiled(Stage):
 
 def lower(sdfg: SDFG, validate: bool = True) -> Lowered:
     """Enter the staged pipeline from a hand-built SDFG."""
-    if validate:
-        sdfg.validate()
+    with tracing.span("lower", program=sdfg.name):
+        if validate:
+            sdfg.validate()
     return Lowered(sdfg)

@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from .compile import (DecodeStepCompiler, attention_layer_shapes,
                       state_specs)
 from .faults import StepWatchdog
@@ -87,6 +88,7 @@ class Request:
     reserved_left: int = 0
     submit_time: float = 0.0
     first_token_time: float = 0.0
+    #: the scheduler's clock when each token was sampled
     token_times: List[float] = dataclasses.field(default_factory=list)
     done: bool = False
     finish_reason: Optional[str] = None  # one of FINISH_REASONS when done
@@ -259,32 +261,46 @@ class Scheduler:
         evicted pages held — and does NOT sample: the last generated
         token is still waiting to be fed to the next decode step, so the
         resumed stream is exactly the unpreempted one."""
-        model, params = self.model, self.params
         seq = req.prompt + req.tokens_out[:-1]
+        if not req.tokens_out:
+            tracing.count("sched.queue_wait_s",
+                          self._clock() - req.submit_time, rid=req.rid)
+        with tracing.span("admit", rid=req.rid, tokens=len(seq)):
+            self._admit_inner(req, slot, total_pages, seq)
+
+    def _admit_inner(self, req: Request, slot: int, total_pages: int,
+                     seq: List[int]):
+        model, params = self.model, self.params
         prompt = jnp.asarray(seq, jnp.int32)[None]
         L = len(seq)
-        cache = model.init_cache(1, L, dtype=self.pool.dtype)
-        logits = None
-        i = 0
-        while i < L:
-            chunk = prompt[:, i:i + self.prefill_chunk]
-            logits, cache = self._prefill_step(params, cache, chunk)
-            i += chunk.shape[1]
+        with tracing.span("prefill"):
+            cache = model.init_cache(1, L, dtype=self.pool.dtype)
+            logits = None
+            i = 0
+            while i < L:
+                chunk = prompt[:, i:i + self.prefill_chunk]
+                logits, cache = self._prefill_step(params, cache, chunk)
+                i += chunk.shape[1]
+                tracing.count("sched.prefill_chunks")
 
-        n_prompt_pages = self.pool.pages_for(L)
-        pages = self.pool.alloc(n_prompt_pages, shard=self._shard_of(slot))
-        req.pages = pages
-        req.reserved_left = total_pages - n_prompt_pages
-        self.block_table[slot, :len(pages)] = pages
+        with tracing.span("scatter"):
+            n_prompt_pages = self.pool.pages_for(L)
+            pages = self.pool.alloc(n_prompt_pages,
+                                    shard=self._shard_of(slot))
+            req.pages = pages
+            req.reserved_left = total_pages - n_prompt_pages
+            self.block_table[slot, :len(pages)] = pages
 
-        for li, layer_cache in self._iter_layer_caches(cache):
-            if "k" in layer_cache:  # attention
-                self.pool.write_prefill(li, pages, layer_cache["k"][0, :L],
-                                        layer_cache["v"][0, :L])
-            else:  # recurrent state rows
-                for key, a in layer_cache.items():
-                    name = f"st{li}__{key}"
-                    self.states[name] = self.states[name].at[slot].set(a[0])
+            for li, layer_cache in self._iter_layer_caches(cache):
+                if "k" in layer_cache:  # attention
+                    self.pool.write_prefill(li, pages,
+                                            layer_cache["k"][0, :L],
+                                            layer_cache["v"][0, :L])
+                else:  # recurrent state rows
+                    for key, a in layer_cache.items():
+                        name = f"st{li}__{key}"
+                        self.states[name] = \
+                            self.states[name].at[slot].set(a[0])
 
         req.slot = slot
         req.pos = L
@@ -295,7 +311,7 @@ class Scheduler:
             first = self._sample(logits[0, -1])
             req.tokens_out.append(first)
             req.first_token_time = self._clock()
-            req.token_times.append(req.first_token_time - req.submit_time)
+            req.token_times.append(req.first_token_time)
             self._maybe_finish(req, first)
 
     def _iter_layer_caches(self, cache):
@@ -534,13 +550,15 @@ class Scheduler:
         toward e.g. a scheduled pressure release. ``n_decode_steps``
         counts compiled steps actually executed."""
         try:
-            return self._step_inner()
+            with tracing.span("step", step=self.n_steps):
+                return self._step_inner()
         finally:
             self.n_steps += 1
 
     def _step_inner(self) -> List[Request]:
         n_done = len(self.finished)
-        self._expire()
+        with tracing.span("expire"):
+            self._expire()
         if self.injector is not None:
             self.injector.on_step_begin(self.n_steps, self)
         self._try_admit()
@@ -548,29 +566,36 @@ class Scheduler:
         if not active:
             return self.finished[n_done:]
 
-        self._bind_pages(active)
+        with tracing.span("bind"):
+            self._bind_pages(active)
         active = [r for r in self.slots if r is not None]
         if not active:
             return self.finished[n_done:]
 
         B, ctx = self._buckets(active)
-        kwargs = self._step_kwargs(B, ctx)
-        step_fn = self.compiler.step_for(B, ctx)
-        result = self._execute(step_fn, kwargs, active, B, ctx)
-        if result is None:  # recompute recovery: no tokens this step
-            return self.finished[n_done:]
-        out, rows, dt, bad = result
-        self.last_logits = out["logits"]
+        with tracing.span("execute", B=B, ctx=ctx):
+            if (B, ctx) not in self.compiler._steps:
+                tracing.count("sched.bucket_first_use", B=B, ctx=ctx)
+            kwargs = self._step_kwargs(B, ctx)
+            step_fn = self.compiler.step_for(B, ctx)
+            result = self._execute(step_fn, kwargs, active, B, ctx)
+            if result is None:  # recompute recovery: no tokens this step
+                return self.finished[n_done:]
+            out, rows, dt, bad = result
+            self.last_logits = out["logits"]
 
-        for li in attention_layer_shapes(self.model):
-            self.pool.k_pages[li] = out[f"kp{li}"]
-            self.pool.v_pages[li] = out[f"vp{li}"]
-        for name in self._sspecs:
-            if B == self.max_slots:
-                # the full slice aliased (and donated) the master buffer
-                self.states[name] = out[name]
-            else:
-                self.states[name] = self.states[name].at[:B].set(out[name])
+            for li in attention_layer_shapes(self.model):
+                self.pool.k_pages[li] = out[f"kp{li}"]
+                self.pool.v_pages[li] = out[f"vp{li}"]
+            for name in self._sspecs:
+                if B == self.max_slots:
+                    # the full slice aliased (and donated) the master buffer
+                    self.states[name] = out[name]
+                else:
+                    self.states[name] = \
+                        self.states[name].at[:B].set(out[name])
+        tracing.count("sched.lanes", B)
+        tracing.count("sched.live_lanes", len(active))
 
         slow = (self.injector.slow_factor_for(self.n_steps)
                 if self.injector is not None else 1.0)
@@ -583,14 +608,15 @@ class Scheduler:
             r.n_failures += 1
             if r.n_failures >= self.max_failures:
                 self._finish(r, "failed")
-        for r in active:
-            if r.done or r.rid in skip:
-                continue  # failed lanes retry (or are done) — no token
-            t = self._sample(rows[r.slot])
-            r.pos += 1
-            r.tokens_out.append(t)
-            r.token_times.append(dt)
-            self._maybe_finish(r, t)
+        with tracing.span("sample"):
+            for r in active:
+                if r.done or r.rid in skip:
+                    continue  # failed lanes retry (or are done) — no token
+                t = self._sample(rows[r.slot])
+                r.pos += 1
+                r.tokens_out.append(t)
+                r.token_times.append(self._clock())
+                self._maybe_finish(r, t)
         return self.finished[n_done:]
 
     def _sample(self, row) -> int:
@@ -701,6 +727,7 @@ class Scheduler:
             r.submit_time += shift
             if r.first_token_time:
                 r.first_token_time += shift
+            r.token_times = [t + shift for t in r.token_times]
             return r
 
         self.queue = deque(req(d) for d in snap["queue"])
@@ -867,6 +894,7 @@ class Scheduler:
             r.submit_time += shift
             if r.first_token_time:
                 r.first_token_time += shift
+            r.token_times = [t + shift for t in r.token_times]
             return r
 
         host_file = {h: os.path.join(d, f"host{h:03d}.npz")
