@@ -120,6 +120,31 @@ def test_axpydot_streamed_kernel(chip):
                       y=((n,), f32), w=((n,), f32))
 
 
+def test_axpydot_paper_size_streams_without_copies(chip):
+    """At the paper's n the lane-dense (n / 128, 128) view of x, y and w is
+    a bitcast: one kernel, and no copy or relayout of the inputs, which
+    would double the HBM traffic."""
+    import math
+    import re
+
+    from benchmarks.axpydot import build
+    n = 209_715_200
+    compiled = lower(build(n)).optimize(
+        [DeviceOffloadPass(), StreamingCompositionPass()]).compile(
+        "pallas", interpret=False, cache=None)
+    f32 = jnp.float32
+    exe = _compile_for_chip(compiled, chip, a=((), f32), x=((n,), f32),
+                            y=((n,), f32), w=((n,), f32))
+    hlo = exe.as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1
+    # every value as large as an input is an input or a view of one
+    inst = re.compile(r"= \w+\[([\d,]+)\]\S* ([\w-]+)\(")
+    for m in inst.finditer(hlo):
+        if math.prod(int(d) for d in m.group(1).split(",")) >= n:
+            assert m.group(2) in ("parameter", "bitcast"), m.group(0)
+    assert exe.memory_analysis().temp_size_in_bytes < n
+
+
 def test_gemver_chain_one_kernel(chip):
     from benchmarks.gemver import _chain_pipeline, build_chain
     n = 384

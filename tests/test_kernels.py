@@ -15,7 +15,10 @@ RNG = np.random.default_rng(42)
 
 
 # -- axpydot ---------------------------------------------------------------
-@pytest.mark.parametrize("n", [1024, 4096, 5000, 16384])
+# 1024-16384: one grid step; 3 << 20: several full (R, 128) blocks;
+# 1053696: a masked last block; 1054000: padded to the tiling, then masked
+@pytest.mark.parametrize("n", [1024, 4096, 5000, 16384, 3 << 20, 1053696,
+                               1054000])
 @pytest.mark.parametrize("dtype", [np.float32, "bfloat16"])
 def test_axpydot_sweep(n, dtype):
     if dtype == "bfloat16":
@@ -27,6 +30,25 @@ def test_axpydot_sweep(n, dtype):
     ref = axpydot.axpydot_ref(a, x, y, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=3e-3 if dtype != np.float32 else 3e-5)
+
+
+@pytest.mark.parametrize("n,itemsize,steps", [
+    (209_715_200, 4, 800),   # the paper's size in float32
+    (209_715_200, 2, 400),
+    (3 << 20, 4, 12),
+    (16384, 4, 1),
+    (16384, 2, 1),
+    (1024, 4, 1),
+])
+def test_axpydot_block_rule(n, itemsize, steps):
+    from repro.kernels.axpydot import kernel
+    assert kernel.sublanes(itemsize) == {4: 8, 2: 16}[itemsize]
+    rows = n // kernel.LANES
+    block = kernel.block_rows(rows, itemsize)
+    assert block % kernel.sublanes(itemsize) == 0
+    assert -(-rows // block) == steps <= 1000
+    # three inputs, double-buffered, within the default scoped VMEM
+    assert 3 * 2 * block * kernel.LANES * itemsize <= kernel.VMEM_LIMIT
 
 
 # -- dot ---------------------------------------------------------------------
