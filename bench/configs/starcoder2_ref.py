@@ -16,7 +16,7 @@ layers stacked on axis 0, ``final_scale``/``final_bias`` and ``lm_head``
 Departures from the published model, all shared with the program: no
 bias on the q, k, v and o projections, and an output head of its own
 in place of the embedding's transpose (see the configuration's
-``reduced_why``).
+``program_gaps``).
 
 ``low`` gives the control, the program's precision with every product
 one step lower: both operands of every product (weights, embedding,

@@ -209,29 +209,46 @@ def profile_options():
     return opts
 
 
+class Traced:
+    """A traced window's profile. ``trace`` is the reduced :class:`Trace`,
+    read on first use after the window, with the program's ``repro.*``
+    spans kept beside the harness's annotations; the raw trace is
+    deleted once read. A serving loop closes its window and goes on
+    serving the requests due in it: reading the profile at the close
+    would hold them back."""
+
+    def __init__(self, trace_dir: Optional[Path] = None):
+        self.dir = trace_dir
+        self._trace = None
+
+    @property
+    def trace(self):
+        if self._trace is None and self.dir is not None:
+            from bench import spans
+            self._trace = spans.load(str(self.dir))
+            shutil.rmtree(self.dir, ignore_errors=True)
+            self.dir = None
+        return self._trace
+
+
 @contextlib.contextmanager
 def traced(enabled: bool, name: str):
     """Trace the body into ``bench-out/trace/<name>`` when ``enabled``;
-    yields a holder whose ``trace`` is the reduced :class:`Trace` after
-    the block. The raw trace is deleted once read."""
-    holder = type("Traced", (), {"trace": None})()
+    yields a :class:`Traced` (with no trace when not enabled)."""
     if not enabled:
         with annotate("window"):
-            yield holder
+            yield Traced()
         return
     import jax
-    from bench import trace as tr
     d = OUT_DIR / "trace" / name
     shutil.rmtree(d, ignore_errors=True)
     d.mkdir(parents=True)
     jax.profiler.start_trace(str(d), profiler_options=profile_options())
     try:
         with annotate("window"):
-            yield holder
+            yield Traced(d)
     finally:
         jax.profiler.stop_trace()
-    holder.trace = tr.load(str(d))
-    shutil.rmtree(d, ignore_errors=True)
 
 
 def memory_peak(devices) -> Optional[int]:

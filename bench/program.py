@@ -37,6 +37,11 @@ class ProgramRun:
     n: int
     itemsize: int
     calls: int
+    #: the program's recorder (``repro.tracing``), ``None`` when off
+    spans: object = None
+    #: the window on the host clock (``time.perf_counter`` seconds)
+    window_start: float = 0.0
+    window_end: float = 0.0
 
 
 def make_inputs(cfg: dict, seed: int):
@@ -130,11 +135,13 @@ def run(cell, seed: int, seconds: float, trace_on: bool, devices,
         f"compiles in the window: {counter.count}")
     run_rec = None
     if trace_on:
+        from repro import tracing
         run_rec = ProgramRun(trace=holder[0].trace,
                              peaks=peaks(devices[0].device_kind),
                              n=int(cfg["n"]),
                              itemsize=np.dtype(cfg["dtype"]).itemsize,
-                             calls=len(results))
+                             calls=len(results), spans=tracing.recorder(),
+                             window_start=t0, window_end=t)
     mem = harness.memory_peak(devices)
     host = {k: np.asarray(v) for k, v in inputs.items()}
     del inputs

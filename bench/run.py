@@ -5,7 +5,8 @@
 The cell, its configuration, its traffic mix and its metrics are found
 by name from ``BENCHMARK.json``. With ``--trace 0`` the result carries
 the cell's end-to-end metrics; with ``--trace 1`` the window, cut to
-``harness.TRACE_SECONDS``, is traced and the result carries its
+``harness.TRACE_SECONDS``, is traced with the program's own spans and
+counters turned on (``repro.tracing``), and the result carries its
 per-layer metrics. The last line of standard
 output is the result, one JSON object; the numbers compared with the
 reference are the last lines of standard error and the result's last
@@ -41,6 +42,9 @@ def main(argv=None) -> int:
         sys.path.insert(0, str(ROOT))
     from bench import harness
     harness.prepare_process()
+    if args.trace:
+        from repro import tracing
+        tracing.enable()
     cell = harness.resolve(args.workload)
     harness.configure_client(cell)
     try:

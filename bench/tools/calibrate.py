@@ -5,10 +5,13 @@ For a serving cell each seed gets a fresh set-up and a short window at
 the cell's own load; then the sample of finished requests goes through
 the reference twice, in float32 (the program's reading) and with every
 product's operands in float8 e4m3 and the residual stream in bfloat16
-(the control's reading, read in the float32 logits). The window's end-to-end metrics are printed beside. For a
-program cell each seed makes fresh inputs; the program's relative error
-against the float64 reference is read beside the control's, the same
-arithmetic computed in bfloat16. One JSON line per seed::
+(the control's reading, read in the float32 logits). The harness's own
+comparison judges each at the cell's limit (``passed``,
+``control_passed``), and the window's end-to-end metrics are printed
+beside. For a program cell each seed makes fresh inputs; the program's
+relative error against the float64 reference is read beside the
+control's, the same arithmetic computed in bfloat16. One JSON line per
+seed::
 
     python bench/tools/calibrate.py --workload sc2-3b.chat \
         --seconds 20 --seeds 11 12 13
@@ -35,10 +38,14 @@ def serving_seed(cell, seed, seconds):
     sess = serving.setup(cell, seed, seconds, log)
     serving.measure(sess, cell.name, seconds, False, counter)
     serving.report(sess, seconds, counter, log)
-    e2e = {**serving.end_to_end(sess.recs, sess.t0, seconds),
-           **serving.latency_detail(sess.recs, sess.t0, seconds)}
+    lat = serving.latencies(sess.recs, sess.t0, seconds)
+    e2e = {**serving.end_to_end(sess.recs, sess.t0, seconds, lat),
+           **serving.latency_detail(lat)}
     checks = serving.check(sess, seed, log, control_dtype=jnp.float8_e4m3fn)
-    return {**e2e, **{k: v["value"] for k, v in checks.items()}}
+    return {**e2e, **{k: v["value"] for k, v in checks.items()},
+            "limit": checks["mean_logit_gap"]["limit"],
+            "passed": serving.passed(checks),
+            "control_passed": serving.passed(serving.as_control(checks))}
 
 
 def program_seed(cell, seed, compiled):

@@ -2,8 +2,8 @@
 
 One set-up, then one window per rate on the same scheduler, each with
 fresh open-loop traffic from the cell's mix at that rate, and a drain
-between windows. For each rate it prints the completed token rate, the
-TTFT and ITL tails, and how many requests due in the window were still
+between windows. For each rate it prints the cell's end-to-end latency
+and token metrics, and how many requests due in the window were still
 queued when it closed: a queue that grows through the window means the
 rate is past the knee. One JSON line per rate::
 
@@ -53,7 +53,9 @@ def main(argv=None) -> int:
                                  contextlib.nullcontext(),
                                  drain_first_tokens=False)
         queued = len(sess.sched.queue)
-        e2e = serving.end_to_end(recs, t0, args.seconds)
+        lat = serving.latencies(recs, t0, args.seconds)
+        e2e = {**serving.end_to_end(recs, t0, args.seconds, lat),
+               **serving.latency_detail(lat)}
         due = [r for r in recs if r.due < t0 + args.seconds]
         first = sum(1 for r in due if r.stamps)
         sess.sched.queue.clear()

@@ -26,10 +26,12 @@ Every seed gets the same work: the lengths are the distribution's
 quantiles at ``(i + 0.5) / n`` (or the cycle's values in turn), paired
 into requests the same way for every seed, and the gaps between
 arrivals are the exponential quantiles, scaled so that all of an open
-loop's ``rate_per_s * seconds`` requests fall due inside the window. A
-seed changes only their order (and the token ids), never the set, and
-only within groups of ten that each span the range of sizes and gaps.
-That keeps runs with different seeds as close as two runs of one seed.
+loop's ``rate_per_s * seconds`` requests fall due inside the window.
+They are dealt in groups of ten that each span the range of sizes and
+gaps, in one order for every seed: a seed draws only the token ids. The
+order is part of the work: with the order drawn from the seed, 61 chat
+requests read a TTFT p80 18% and tokens/s 8% apart from seed to seed,
+while two runs of one seed stayed within 5% and 0.4%.
 """
 from __future__ import annotations
 
@@ -93,10 +95,12 @@ def count(mix: dict, seconds: float) -> int:
     raise ValueError(f"traffic kind {mix['kind']!r} carries no requests")
 
 
-#: requests of an open loop whose lengths and gaps a seed shuffles among
+#: requests of an open loop whose lengths and gaps are shuffled among
 #: themselves: each such group spans the whole range of sizes, so every
-#: stretch of the window carries the same load whatever the seed
+#: stretch of the window carries the same load
 GROUP = 10
+#: the stream that orders every seed's requests
+ORDER = 0
 
 
 def _shuffled(groups, rng) -> np.ndarray:
@@ -148,14 +152,14 @@ def generate(mix: dict, seed: int, seconds: float, vocab: int,
     if mix["output"]["dist"] != "cycle":
         # quantiles come sorted: pair prompts and outputs by a shuffle
         # that is the same for every seed
-        outputs = _rng(0, 1).permutation(outputs)
-    rng = _rng(seed, 0)
+        outputs = _rng(ORDER, 1).permutation(outputs)
+    rng = _rng(ORDER, 0)
     by_size = np.lexsort((outputs, prompts))
     order = by_size[_shuffled(_spread_groups(n), rng)]
     # exponential gaps as quantiles, dealt and shuffled the same way,
     # scaled so that the last request falls due inside the window
     gaps = np.asarray([-math.log(1.0 - (i + .5) / n) for i in range(n)])
-    gaps = gaps[_shuffled(_spread_groups(n), _rng(seed, 2))]
+    gaps = gaps[_shuffled(_spread_groups(n), _rng(ORDER, 2))]
     gaps *= seconds / gaps.sum()
     due = np.cumsum(gaps) - gaps[0]
     arrivals = mix.get("arrivals", "poisson")

@@ -57,11 +57,11 @@ class CpuAsChip:
         return None
 
 
-def run(cell, seed: int, seconds: float = 2.0):
+def run(cell, seed: int, seconds: float = 2.0, trace: bool = False):
     import jax
     del jax  # the driver imports it; keep JAX on the CPU the tests use
     return harness.driver(cell.config).run(
-        cell, seed, seconds, False, [CpuAsChip()], harness.CompileCounter(),
+        cell, seed, seconds, trace, [CpuAsChip()], harness.CompileCounter(),
         lambda m: None)
 
 

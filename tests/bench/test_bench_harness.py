@@ -134,6 +134,9 @@ def test_traffic_keeps_the_work_across_seeds():
     b = traffic.generate(mix, 2**40 + 7, 200, 49152, 512)
     assert len(a) == len(b) == round(mix["rate_per_s"] * 200)
     assert _work(a) == _work(b)
+    # one order for every seed: the seed draws the token ids alone
+    assert [(r.due_s, len(r.prompt), r.max_new_tokens) for r in a] == \
+        [(r.due_s, len(r.prompt), r.max_new_tokens) for r in b]
     assert [r.prompt for r in a] != [r.prompt for r in b]
     assert all(len(r.prompt) % 64 == 0 for r in a)
     assert all(len(r.prompt) + r.max_new_tokens < 512 for r in a)

@@ -32,3 +32,6 @@ def test_float8_control_fails_the_limit(seed):
                            control_dtype=jnp.float8_e4m3fn)
     assert checks["mean_logit_gap"]["value"] <= TINY_MEAN_GAP
     assert checks["control_mean_logit_gap"]["value"] > TINY_MEAN_GAP
+    # the harness's own comparison rules the control not correct
+    assert serving.passed(checks)
+    assert not serving.passed(serving.as_control(checks))

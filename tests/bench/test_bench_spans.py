@@ -182,20 +182,22 @@ def test_readers_find_nothing_without_program_spans(name):
 
 
 def test_compile_readers_on_a_real_program_run(monkeypatch):
-    """The AXPYDOT cell run on the CPU with tracing on: its compile
-    before the window splits into the staged compiler's part and JAX's,
-    and both lie inside the set-up."""
+    """The AXPYDOT cell run traced on the CPU with tracing on: its run
+    record carries the recorder and the window; its compile before the
+    window splits into the staged compiler's part and JAX's, and both
+    lie inside the set-up."""
     monkeypatch.setattr(program, "require_compiled", lambda r, c: None)
     t0 = time.perf_counter()
     rec = tracing.enable()
     try:
         # a size no other test compiles, so that nothing comes from a cache
-        out = run_cell(tiny_program_cell(3 << 13), seed=5, seconds=0.3)
+        out = run_cell(tiny_program_cell(3 << 13), seed=5, seconds=0.3,
+                       trace=True)
     finally:
         tracing.disable()
-    run = types.SimpleNamespace(trace=None, spans=rec,
-                                window_start=out.window_start,
-                                window_end=time.perf_counter())
+    run = out.run
+    assert run.spans is rec
+    assert run.window_start == out.window_start < run.window_end
     assert out.correct, out.checks
     sdfg = harness.metric_reader("sdfg_compile_s.axpydot")(run)
     xla = harness.metric_reader("xla_compile_s.axpydot")(run)
