@@ -254,19 +254,26 @@ class KVPagePool:
             self.v_pages[li] = jnp.asarray(snap["v_pages"][li], self.dtype)
 
     # -- storage --------------------------------------------------------
-    def write_prefill(self, li: int, pages: List[int], k, v):
-        """Scatter a prefilled (S, Hkv, Dh) K/V slab into ``pages``.
-        S is padded up to a whole number of pages (pad rows are past the
-        sequence position, hence masked at attention time)."""
+    def install_prefill(self, k_pages: Dict[int, jnp.ndarray],
+                        v_pages: Dict[int, jnp.ndarray], page_ids,
+                        kv: Dict[int, Tuple[jnp.ndarray, jnp.ndarray]]):
+        """Scatter every attention layer's prefilled K/V into the pages
+        ``page_ids`` names and return the new ``(k_pages, v_pages)``.
+
+        ``kv`` maps flat layer index -> ``(k, v)`` slabs of shape
+        ``(S, Hkv, Dh)``; S is padded with zeros up to a whole number of
+        pages (pad rows are past the sequence position, hence masked at
+        attention time). Pure in its arguments, so the scheduler traces
+        it inside one jitted install per admission."""
         ps = self.page_size
-        s = k.shape[0]
-        pad = len(pages) * ps - s
-        if pad < 0:
-            raise PageError(f"{len(pages)} pages cannot hold {s} tokens")
-        idx = jnp.asarray(pages, jnp.int32)
-        kp = jnp.pad(k, ((0, pad), (0, 0), (0, 0))).reshape(
-            len(pages), ps, *k.shape[1:]).astype(self.dtype)
-        vp = jnp.pad(v, ((0, pad), (0, 0), (0, 0))).reshape(
-            len(pages), ps, *v.shape[1:]).astype(self.dtype)
-        self.k_pages[li] = self.k_pages[li].at[idx].set(kp)
-        self.v_pages[li] = self.v_pages[li].at[idx].set(vp)
+        n = page_ids.shape[0]
+        k_pages, v_pages = dict(k_pages), dict(v_pages)
+        for li, (k, v) in kv.items():
+            pad = n * ps - k.shape[0]
+            if pad < 0:
+                raise PageError(f"{n} pages cannot hold {k.shape[0]} tokens")
+            for slab, dst in ((k, k_pages), (v, v_pages)):
+                slab = jnp.pad(slab, ((0, pad), (0, 0), (0, 0))).reshape(
+                    n, ps, *slab.shape[1:]).astype(self.dtype)
+                dst[li] = dst[li].at[page_ids].set(slab)
+        return k_pages, v_pages

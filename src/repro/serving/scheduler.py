@@ -188,6 +188,13 @@ class Scheduler:
         self._next_rid = 0
         self._admit_seq = 0
         self._prefill_step = jax.jit(model.decode_step)
+        # one device call installs a prefill into every layer's pages and
+        # state rows; it donates them exactly when the decode step does.
+        # A scatter's result keeps its operand's sharding, so a sharded
+        # pool stays in the layout its compiled steps were built for
+        self._install = jax.jit(
+            self._install_prefill,
+            donate_argnums=(3, 4, 5) if self.compiler.donate else ())
         self.n_steps = 0         # scheduler iterations — the fault clock
         self.n_decode_steps = 0  # compiled decode steps actually executed
         if injector is not None:
@@ -290,17 +297,12 @@ class Scheduler:
             req.pages = pages
             req.reserved_left = total_pages - n_prompt_pages
             self.block_table[slot, :len(pages)] = pages
-
-            for li, layer_cache in self._iter_layer_caches(cache):
-                if "k" in layer_cache:  # attention
-                    self.pool.write_prefill(li, pages,
-                                            layer_cache["k"][0, :L],
-                                            layer_cache["v"][0, :L])
-                else:  # recurrent state rows
-                    for key, a in layer_cache.items():
-                        name = f"st{li}__{key}"
-                        self.states[name] = \
-                            self.states[name].at[slot].set(a[0])
+            (self.pool.k_pages, self.pool.v_pages,
+             self.states) = self._install(
+                cache, np.asarray(pages, np.int32), np.int32(slot),
+                self.pool.k_pages, self.pool.v_pages, self.states)
+            tracing.count("sched.scatter_calls", 1,
+                          layers=len(self.pool.k_pages), pages=len(pages))
 
         req.slot = slot
         req.pos = L
@@ -314,17 +316,30 @@ class Scheduler:
             req.token_times.append(req.first_token_time)
             self._maybe_finish(req, first)
 
-    def _iter_layer_caches(self, cache):
-        """(flat layer index, per-layer cache dict) in execution order."""
+    def _install_prefill(self, cache, page_ids, slot, k_pages, v_pages,
+                         states):
+        """Traced body of the install: a batch-1 prefill cache (exactly
+        as ``_prefill_step`` returns it, sized to the sequence) into the
+        pages ``page_ids`` names and the recurrent-state rows of
+        ``slot``. A layer's kind is read from its cache: attention
+        layers hold ``k``/``v``, every other layer's entries are state
+        rows. Returns the new page and state dicts."""
         pi_count = len(self.model.period_specs)
-        li = 0
-        for pp in range(self.model.n_periods):
-            for pi in range(pi_count):
-                yield li, jax.tree.map(lambda a: a[pp], cache["body"][pi])
-                li += 1
-        for c in cache["tail"]:
-            yield li, c
-            li += 1
+        layers = [jax.tree.map(lambda a, pp=pp: a[pp], cache["body"][pi])
+                  for pp in range(self.model.n_periods)
+                  for pi in range(pi_count)] + list(cache["tail"])
+        kv = {}
+        states = dict(states)
+        for li, layer_cache in enumerate(layers):
+            if "k" in layer_cache:  # attention
+                kv[li] = (layer_cache["k"][0], layer_cache["v"][0])
+            else:  # recurrent state rows
+                for key, a in layer_cache.items():
+                    name = f"st{li}__{key}"
+                    states[name] = states[name].at[slot].set(a[0])
+        k_pages, v_pages = self.pool.install_prefill(k_pages, v_pages,
+                                                     page_ids, kv)
+        return k_pages, v_pages, states
 
     # -- finishing / eviction / preemption --------------------------------
     def _maybe_finish(self, req: Request, last_token: int):

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro import tracing
 from repro.models.transformer import TransformerLM
 from repro.pipeline.cache import (CACHE_SIZE_ENV, CompilationCache,
                                   _default_max_entries)
@@ -94,10 +95,156 @@ class TestPagePool:
         pool = self._pool()
         pages = pool.alloc(2, reserved=False)
         k = jnp.ones((6, 2, 8), jnp.float32)  # 6 tokens over 2x4-slot pages
-        pool.write_prefill(0, pages, k, 2 * k)
-        got = pool.k_pages[0][jnp.asarray(pages)].reshape(8, 2, 8)
+        kp, vp = pool.install_prefill(pool.k_pages, pool.v_pages,
+                                      jnp.asarray(pages, jnp.int32),
+                                      {0: (k, 2 * k)})
+        got = kp[0][jnp.asarray(pages)].reshape(8, 2, 8)
         assert np.all(np.asarray(got[:6]) == 1.0)
         assert np.all(np.asarray(got[6:]) == 0.0)
+        got = vp[0][jnp.asarray(pages)].reshape(8, 2, 8)
+        assert np.all(np.asarray(got[:6]) == 2.0)
+        assert np.all(np.asarray(got[6:]) == 0.0)
+
+    def test_install_prefill_rejects_too_few_pages(self):
+        pool = self._pool()
+        k = jnp.ones((9, 2, 8), jnp.float32)  # 9 tokens, 2 pages hold 8
+        with pytest.raises(PageError):
+            pool.install_prefill(pool.k_pages, pool.v_pages,
+                                 jnp.asarray([1, 2], jnp.int32), {0: (k, k)})
+
+
+# ---------------------------------------------------------------------------
+# Installing a prefill: one jitted call writes every layer's pages
+# ---------------------------------------------------------------------------
+def _bits(a) -> np.ndarray:
+    a = np.ascontiguousarray(np.asarray(a))
+    return a.view(np.uint8)
+
+
+def _install_reference(model, cache, pages, slot, k_pages, v_pages, states,
+                       page_size):
+    """The per-layer install on the host: each attention layer's K/V
+    padded to whole pages and set into ``pages``, each recurrent layer's
+    entries set into row ``slot`` of its state arrays."""
+    k_pages = {li: np.array(a) for li, a in k_pages.items()}
+    v_pages = {li: np.array(a) for li, a in v_pages.items()}
+    states = {name: np.array(a) for name, a in states.items()}
+    layers = [{key: np.asarray(a[pp]) for key, a in cache["body"][pi].items()}
+              for pp in range(model.n_periods)
+              for pi in range(len(model.period_specs))]
+    layers += [{key: np.asarray(a) for key, a in c.items()}
+               for c in cache["tail"]]
+    for li, layer in enumerate(layers):
+        if "k" in layer:
+            for key, dst in (("k", k_pages), ("v", v_pages)):
+                a = layer[key][0]
+                slab = np.zeros((len(pages) * page_size,) + a.shape[1:],
+                                dst[li].dtype)
+                slab[:len(a)] = a
+                dst[li][pages] = slab.reshape(len(pages), page_size,
+                                              *a.shape[1:])
+        else:
+            for key, a in layer.items():
+                states[f"st{li}__{key}"][slot] = a[0]
+    return k_pages, v_pages, states
+
+
+def _random_like(tree, seed):
+    leaves, treedef = jax.tree.flatten(tree)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    return jax.tree.unflatten(treedef, [
+        a if not jnp.issubdtype(a.dtype, jnp.floating)
+        else jax.random.normal(k, a.shape, jnp.float32).astype(a.dtype)
+        for k, a in zip(keys, leaves)])
+
+
+class TestPrefillInstall:
+    @pytest.mark.parametrize("arch", ["starcoder2-3b", "rwkv6-7b"])
+    def test_install_matches_per_layer_reference(self, arch):
+        """Random prior pages and state rows, a random prefill cache of 11
+        tokens over 3 pages of 4: the named pages hold the cache with zero
+        pad rows, the named slot holds the states, and every other page
+        and row keeps its bits."""
+        model, params = _model(arch, f32=False)
+        sched = Scheduler(model, params, max_slots=4, page_size=4,
+                          n_pages=16, max_model_len=32,
+                          compile_cache=CompilationCache())
+        L, pages, slot = 11, [5, 2, 9], 2
+        sched.pool.k_pages = _random_like(sched.pool.k_pages, 1)
+        sched.pool.v_pages = _random_like(sched.pool.v_pages, 2)
+        sched.states = _random_like(sched.states, 3)
+        cache = _random_like(model.init_cache(1, L, dtype=sched.pool.dtype),
+                             4)
+        ref = _install_reference(model, cache, pages, slot,
+                                 sched.pool.k_pages, sched.pool.v_pages,
+                                 sched.states, sched.page_size)
+        got = sched._install(cache, np.asarray(pages, np.int32),
+                             np.int32(slot), sched.pool.k_pages,
+                             sched.pool.v_pages, sched.states)
+        for want, have in zip(ref, got):
+            assert set(want) == set(have)
+            for key in want:
+                np.testing.assert_array_equal(_bits(have[key]),
+                                              _bits(want[key]), err_msg=key)
+        k_pages, v_pages, states = got
+        if arch == "rwkv6-7b":
+            assert not k_pages and states
+        else:
+            assert len(k_pages) == model.cfg.n_layers and not states
+            for pg in (k_pages, v_pages):
+                for a in pg.values():
+                    tail = np.asarray(a[pages[-1]], np.float32)
+                    assert np.all(tail[L - 2 * 4:] == 0.0)  # pad rows
+                    assert np.any(tail[:L - 2 * 4] != 0.0)
+
+    def test_two_prompts_of_one_length_compile_the_install_once(self):
+        model, params = _model("starcoder2-3b", f32=False)
+        sched = Scheduler(model, params, max_slots=4, page_size=8,
+                          n_pages=32, max_model_len=64, prefill_chunk=4,
+                          compile_cache=CompilationCache())
+        sched.submit([3, 1, 4, 1, 5], 3)
+        sched.step()
+        sched.submit([2, 7, 1, 8, 2], 3)
+        sched.step()
+        assert sched._install._cache_size() == 1
+        sched.submit([1, 4, 1], 3)
+        sched.run()
+        assert sched._install._cache_size() == 2
+
+    def test_one_scatter_call_per_admission(self):
+        model, params = _model("starcoder2-3b", f32=False)
+        sched = Scheduler(model, params, max_slots=4, page_size=8,
+                          n_pages=32, max_model_len=64, prefill_chunk=4,
+                          compile_cache=CompilationCache())
+        prompts = [[3, 1, 4, 1, 5, 9, 2, 6, 5], [2, 7], [1, 8, 2, 8]]
+        rec = tracing.enable()
+        try:
+            for p in prompts:
+                sched.submit(p, 3)
+            sched.run()
+        finally:
+            tracing.disable()
+        calls = rec.counts("sched.scatter_calls")
+        assert len(calls) == len(rec.spans("admit")) == len(prompts)
+        assert all(c.value == 1 and c.span.name == "scatter" for c in calls)
+        assert [c.attrs["pages"] for c in calls] == [2, 1, 1]
+        assert all(c.attrs["layers"] == model.cfg.n_layers for c in calls)
+
+    @pytest.mark.parametrize("donate", [True, False])
+    def test_install_donates_when_the_step_does(self, donate):
+        model, params = _model("starcoder2-3b", f32=False)
+        sched = Scheduler(model, params, max_slots=4, page_size=8,
+                          n_pages=32, max_model_len=64, prefill_chunk=4,
+                          compile_cache=CompilationCache(), donate=donate)
+        assert sched.compiler.donate is donate
+        before = (list(sched.pool.k_pages.values())
+                  + list(sched.pool.v_pages.values()))
+        sched.submit([3, 1, 4, 1, 5], 3)
+        sched._try_admit()
+        assert all(a.is_deleted() is donate for a in before)
+        assert not any(a.is_deleted() for a in sched.pool.k_pages.values())
+        sched.run()
+        sched.check_invariants()
 
 
 # ---------------------------------------------------------------------------

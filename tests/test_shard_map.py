@@ -409,6 +409,20 @@ SERVE_SCRIPT = textwrap.dedent("""
     out["shrink_eq"] = {r.rid: list(r.tokens_out)
                         for r in s3.run()} == base
     s3.check_invariants()
+
+    # an admission's install leaves every page array in the layout the
+    # compiled step gave it
+    s4 = Scheduler(model, params, n_shards=2, **KW)
+    s4.submit(PROMPTS[0], 5)
+    s4.step()
+    pages = lambda: [a for pg in (s4.pool.k_pages, s4.pool.v_pages)
+                     for a in pg.values()]
+    before = [a.sharding for a in pages()]
+    out["pages_on_mesh"] = all(len(sh.device_set) == 2 for sh in before)
+    s4.submit(PROMPTS[1], 5)
+    s4._try_admit()
+    out["admitted"] = sum(r is not None for r in s4.slots)
+    out["admit_keeps_sharding"] = [a.sharding for a in pages()] == before
     print(json.dumps(out))
 """)
 
@@ -462,6 +476,11 @@ class TestShardedServing:
         assert serve_sub["recompute_kept_tokens"] > 0
         assert serve_sub["watchdog_shard_lost"]
         assert serve_sub["hostloss_eq"]
+
+    def test_admission_keeps_page_sharding(self, serve_sub):
+        assert serve_sub["pages_on_mesh"]
+        assert serve_sub["admitted"] == 2
+        assert serve_sub["admit_keeps_sharding"]
 
     def test_live_shrink_preempts_and_stays_exact(self, serve_sub):
         assert "mesh_shrink" in serve_sub["shrink_events"]
